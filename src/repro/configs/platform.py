@@ -20,15 +20,31 @@ Lowering map (``kernel_lowering``):
                       interpret mode for the shard_map'd kernel calls
                       (DESIGN.md Section 10)
 
-Environment overrides: ``GRIFFIN_PLATFORM`` picks the platform without a
-code change; ``set_host_device_count`` is the in-process twin of the CI
-``XLA_FLAGS=--xla_force_host_platform_device_count=8`` export.
+Interpret mode follows the backend the arrays live on and nothing else:
+``kernel_interpret()`` asks jax, and ``checked_interpret`` refuses an
+interpret-mode kernel on a TPU backend, so no setting can quietly run the
+kernels in the interpreter on the chip.
+
+Environment overrides: ``GRIFFIN_PLATFORM`` picks the platform
+``set_platform`` pins (before the backend starts); it never changes the
+lowering of an already-running backend.  ``set_host_device_count`` is the
+in-process twin of the CI ``XLA_FLAGS=--xla_force_host_platform_device_count=8``
+export.  ``enable_compile_cache`` turns on JAX's persistent compilation
+cache at one fixed place.
 """
 from __future__ import annotations
 
 import os
 import warnings
+from pathlib import Path
 from typing import Optional
+
+# the checkout root (src/repro/configs/platform.py -> three levels up)
+REPO_ROOT = Path(__file__).resolve().parents[3]
+# where compiled programs are cached when JAX_COMPILATION_CACHE_DIR is not
+# set: a fixed path, since the path is part of the cache key (listed in
+# .gitignore)
+DEFAULT_COMPILE_CACHE = REPO_ROOT / ".jax_cache"
 
 # staged GPU performance flags (jax.readthedocs.io gpu_performance_tips,
 # via the bayespec snippet): applied by set_platform("gpu") so the future
@@ -96,8 +112,12 @@ def set_host_device_count(n: int) -> None:
 
 def kernel_lowering(platform: Optional[str] = None) -> str:
     """'mosaic' | 'triton' | 'interpret' — how pallas_call should lower on
-    ``platform`` (default: the active backend)."""
-    return _LOWERING[resolve_platform(platform)]
+    ``platform`` (default: the backend jax is running, never the
+    ``GRIFFIN_PLATFORM`` override)."""
+    if platform is None:
+        import jax
+        platform = jax.default_backend()
+    return _LOWERING[platform.lower()]
 
 
 def kernel_interpret(platform: Optional[str] = None) -> bool:
@@ -110,3 +130,32 @@ def kernel_interpret(platform: Optional[str] = None) -> bool:
     through by hand (single-device callers keep passing it explicitly).
     """
     return kernel_lowering(platform) == "interpret"
+
+
+def checked_interpret(interpret: bool) -> bool:
+    """``interpret`` for a ``pallas_call``, refused on a TPU backend:
+    the interpreter there would hide that the kernel never reached the
+    chip.  Every kernel wrapper passes its flag through here."""
+    if interpret:
+        import jax
+        if jax.default_backend() == "tpu":
+            raise RuntimeError("interpret-mode Pallas kernel requested on "
+                               "a TPU backend")
+    return interpret
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    With ``JAX_COMPILATION_CACHE_DIR`` set, JAX already reads that
+    directory and nothing here overrides it.  Otherwise the cache goes to
+    ``DEFAULT_COMPILE_CACHE`` inside the checkout — the same path on every
+    call and every run, so later runs hit it.  Call before the first
+    compile.
+    """
+    import jax
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(DEFAULT_COMPILE_CACHE))
+    return str(DEFAULT_COMPILE_CACHE)

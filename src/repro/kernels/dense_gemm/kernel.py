@@ -14,6 +14,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
+from ...configs.platform import checked_interpret
+
 
 def _matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, nk: int):
     """Grid (mt, nt, kt): accumulate A[i,k] @ B[k,j] into a VMEM f32 scratch,
@@ -56,5 +58,5 @@ def dense_matmul_kernel(a: jax.Array, b: jax.Array, *, block_m: int,
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        interpret=interpret,
+        interpret=checked_interpret(interpret),
     )(a, b)

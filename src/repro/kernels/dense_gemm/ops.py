@@ -71,17 +71,16 @@ def dense_matmul(a: jax.Array, b: jax.Array, *,
     ``shardable(b, mesh.shape[mesh_axis])``.
     """
     if mesh is not None:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         assert shardable(b, mesh.shape[mesh_axis]), \
             (b.shape, dict(mesh.shape), mesh_axis)
         local = functools.partial(dense_matmul_shard, block_m=block_m,
                            block_n=block_n, block_k=block_k,
                            interpret=interpret)
-        return shard_map(local, mesh=mesh,
-                         in_specs=(P(), P(None, mesh_axis)),
-                         out_specs=P(None, mesh_axis),
-                         check_rep=False)(a, b)
+        return jax.shard_map(local, mesh=mesh,
+                             in_specs=(P(), P(None, mesh_axis)),
+                             out_specs=P(None, mesh_axis),
+                             check_vma=False)(a, b)
     return _dense_matmul_jit(a, b, block_m=block_m, block_n=block_n,
                              block_k=block_k, interpret=interpret)
 

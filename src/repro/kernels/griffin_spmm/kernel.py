@@ -28,6 +28,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
+from ...configs.platform import checked_interpret
+
 
 def _spmm_kernel(kidx_ref, cnt_ref, a_ref, b_ref, o_ref, acc_ref,
                  *, nkc: int, dual: bool):
@@ -41,9 +43,13 @@ def _spmm_kernel(kidx_ref, cnt_ref, a_ref, b_ref, o_ref, acc_ref,
     live = kc < cnt_ref[j]
     if dual:
         # Dual sparsity: also skip when the (dynamic) A tile is all-zero —
-        # the paper's on-the-fly zero detection at block granularity.
+        # the paper's on-the-fly zero detection at block granularity.  The
+        # test runs on an f32 copy: Mosaic cannot reduce the packed i1 mask
+        # a bf16 compare produces, and widening keeps every value (and so
+        # the predicate) exact.
         a_blk = a_ref[...]
-        live = jnp.logical_and(live, jnp.any(a_blk != 0))
+        live = jnp.logical_and(
+            live, jnp.any(a_blk.astype(jnp.float32) != 0))
 
         @pl.when(live)
         def _acc_dual():
@@ -103,5 +109,5 @@ def griffin_spmm_kernel(a: jax.Array, b_comp: jax.Array, kidx: jax.Array,
             scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        interpret=interpret,
+        interpret=checked_interpret(interpret),
     )(flat_kidx, cnt.astype(jnp.int32), a, b_comp)

@@ -12,6 +12,9 @@ jit, ``lax.scan`` over stacked layers, and the sharding rules in
 runtime.sharding (DESIGN.md Section 4).  ``stack_weights`` builds the
 stacked (leading layer/expert axis) form the model stacks consume;
 indexing a stacked instance (``gw[i]``) slices every array leaf.
+Preprocessing and stacking return host numpy leaves: the serving engines
+place them once, each shard straight onto its own device, so a compacted
+model never has to fit on one chip first.
 """
 from __future__ import annotations
 
@@ -128,16 +131,23 @@ def preprocess_weights(w: np.ndarray, *, block_k: int = DEFAULT_BLOCK_K,
     k, n = w.shape
     pk = -(-k // block_k) * block_k
     pn = -(-n // block_n) * block_n
-    wp = np.zeros((pk, pn), dtype=w.dtype)
-    wp[:k, :n] = w
+    wp = w
+    if (pk, pn) != (k, n):
+        wp = np.zeros((pk, pn), dtype=w.dtype)
+        wp[:k, :n] = w
     nb_k, nb_n = pk // block_k, pn // block_n
     unit = unit or max(8, block_n // 4)
 
     inv_perm = None
     if balance and pn > block_n and pn % unit == 0:
         full_perm = balance_columns(wp, block_k, block_n, unit)
-        wp = wp[:, full_perm]
-        inv_perm = jnp.asarray(np.argsort(full_perm).astype(np.int32))
+        # the permutation moves whole unit-wide column groups: gather them
+        # as contiguous chunks (a per-column gather is several times
+        # slower on published-width matrices)
+        order = full_perm[::unit] // unit
+        wp = np.take(wp.reshape(pk, pn // unit, unit), order,
+                     axis=1).reshape(pk, pn)
+        inv_perm = np.argsort(full_perm).astype(np.int32)
 
     blk_nz = (wp.reshape(nb_k, block_k, nb_n, block_n) != 0).any(axis=(1, 3))
     cnt = blk_nz.sum(axis=0).astype(np.int32)                 # (nb_n,)
@@ -154,17 +164,16 @@ def preprocess_weights(w: np.ndarray, *, block_k: int = DEFAULT_BLOCK_K,
                    j * block_n:(j + 1) * block_n] = \
                 wp[kb * block_k:(kb + 1) * block_k,
                    j * block_n:(j + 1) * block_n]
-    return GriffinWeights(
-        b_comp=jnp.asarray(b_comp), kidx=jnp.asarray(kidx),
-        cnt=jnp.asarray(cnt), inv_perm=inv_perm, k=pk, n=n,
-        block_k=block_k, block_n=block_n)
+    return GriffinWeights(b_comp=b_comp, kidx=kidx, cnt=cnt,
+                          inv_perm=inv_perm, k=pk, n=n,
+                          block_k=block_k, block_n=block_n)
 
 
 def stack_weights(gws: Sequence[GriffinWeights]) -> GriffinWeights:
     """Stack per-layer/per-expert compacted weights along a new leading
     axis, padding every member to the common (max over members) grid depth
     so the stacked leaves are rectangular — the layout ``lax.scan`` and the
-    unrolled layer loop both consume."""
+    unrolled layer loop both consume.  Host numpy in, host numpy out."""
     assert gws, "empty stack"
     g0 = gws[0]
     for g in gws[1:]:
@@ -178,22 +187,22 @@ def stack_weights(gws: Sequence[GriffinWeights]) -> GriffinWeights:
 
     def padded(g: GriffinWeights):
         pad_c = max_cnt - g.kidx.shape[-1]
-        kidx, b_comp = g.kidx, g.b_comp
+        kidx, b_comp = np.asarray(g.kidx), np.asarray(g.b_comp)
         if pad_c:
             # dead entries (kc >= cnt) — clamp-repeat the last id, zero data
-            kidx = jnp.concatenate(
-                [kidx, jnp.repeat(kidx[:, -1:], pad_c, axis=1)], axis=1)
-            b_comp = jnp.concatenate(
-                [b_comp, jnp.zeros((pad_c * bk, b_comp.shape[1]),
-                                   b_comp.dtype)], axis=0)
+            kidx = np.concatenate(
+                [kidx, np.repeat(kidx[:, -1:], pad_c, axis=1)], axis=1)
+            b_comp = np.concatenate(
+                [b_comp, np.zeros((pad_c * bk, b_comp.shape[1]),
+                                  b_comp.dtype)], axis=0)
         return kidx, b_comp
 
     ks, bs = zip(*[padded(g) for g in gws])
     return GriffinWeights(
-        b_comp=jnp.stack(bs), kidx=jnp.stack(ks),
-        cnt=jnp.stack([g.cnt for g in gws]),
+        b_comp=np.stack(bs), kidx=np.stack(ks),
+        cnt=np.stack([np.asarray(g.cnt) for g in gws]),
         inv_perm=(None if g0.inv_perm is None
-                  else jnp.stack([g.inv_perm for g in gws])),
+                  else np.stack([np.asarray(g.inv_perm) for g in gws])),
         k=g0.k, n=g0.n, block_k=g0.block_k, block_n=g0.block_n,
         a_thr=g0.a_thr)
 
@@ -259,15 +268,14 @@ def shardable(gw: GriffinWeights, n_shards: int) -> bool:
 
 def _shard_map_run(ap, gw: GriffinWeights, mesh, axis, *, block_m, dual,
                    interpret):
-    from jax.experimental.shard_map import shard_map
     in_specs, out_spec = shard_specs(axis)
     local = functools.partial(
         griffin_matmul_shard, block_m=block_m, block_k=gw.block_k,
         block_n=gw.block_n, dual=dual, interpret=interpret)
-    # check_rep=False: pallas_call has no replication rule either — the
+    # check_vma=False: pallas_call has no replication rule either — the
     # out_spec states the (easily checked) fact that shards are disjoint
-    out = shard_map(local, mesh=mesh, in_specs=in_specs,
-                    out_specs=out_spec, check_rep=False)(
+    out = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                        out_specs=out_spec, check_vma=False)(
                         ap, gw.b_comp, gw.kidx, gw.cnt)
     if gw.inv_perm is not None:
         out = out[:, gw.inv_perm]

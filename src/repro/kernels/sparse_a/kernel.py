@@ -30,6 +30,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
+from ...configs.platform import checked_interpret
+
 
 def _sparse_a_kernel(kidx_ref, cnt_ref, a_ref, b_ref, o_ref, acc_ref,
                      *, nkc: int):
@@ -93,5 +95,5 @@ def sparse_a_gemm_kernel(a: jax.Array, b: jax.Array, kidx: jax.Array,
             scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        interpret=interpret,
+        interpret=checked_interpret(interpret),
     )(flat_kidx, cnt.astype(jnp.int32), a, b)

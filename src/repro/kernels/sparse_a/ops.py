@@ -182,15 +182,14 @@ def sparse_a_matmul(a: jax.Array, w: jax.Array, *,
     bm, bk = meta.block_m, meta.block_k
     ap = _pad2(a, meta.m, meta.k)
     if mesh is not None:
-        from jax.experimental.shard_map import shard_map
         assert shardable(w, mesh.shape[mesh_axis]), \
             (w.shape, dict(mesh.shape), mesh_axis)
         in_specs, out_spec = shard_specs(mesh_axis)
         local = functools.partial(sparse_a_matmul_shard, block_m=bm,
                                   block_k=bk, block_n=block_n,
                                   interpret=interpret)
-        out = shard_map(local, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_spec, check_rep=False)(
+        out = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                            out_specs=out_spec, check_vma=False)(
                             ap, _pad2(w, meta.k, n), meta.kidx, meta.cnt)
         return out[:m]
     bn = min(block_n, _rup(n))

@@ -11,8 +11,10 @@ jitted prefill/decode fns and shardings come from
 
 On CPU this drives a reduced config (examples/sparse_serve.py, the
 scripts/ci.sh serve stage); on TPU the same code serves the full
-configs.  ``--parity`` replays every request through the batch-1
-``greedy_generate`` oracle and asserts token-identical output.
+configs, which ``chip_smoke.py`` at the repository root drives through
+``prepare`` / ``build_engine`` / ``parity_mismatches``.  ``--parity``
+replays every request through the batch-1 ``greedy_generate`` oracle and
+asserts token-identical output.
 """
 from __future__ import annotations
 
@@ -24,7 +26,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
-from repro.configs.platform import kernel_interpret
+from repro.configs.platform import enable_compile_cache, kernel_interpret
 from repro.models import build_model
 from repro.launch.mesh import mesh_spec, serve_mesh
 from repro.runtime import slo
@@ -32,7 +34,7 @@ from repro.runtime.config import EngineConfig
 from repro.runtime.elastic import plan_mesh
 from repro.runtime.engine import ServeEngine, synthetic_trace
 from repro.runtime.fault import parse_fault_spec
-from repro.runtime.mesh_serve import MeshServeEngine
+from repro.runtime.mesh_serve import MeshServeEngine, init_params_sharded
 from repro.runtime.router import RouterEngine
 from repro.runtime.serve import greedy_generate, jit_serve_fns
 from repro.runtime.slo import DegradationConfig
@@ -209,25 +211,48 @@ def _run_router(api, params, args, mesh, cfg, fam_plan, reqs,
         if any(len(e.mode_history) > 1 for e in engines if e is not None):
             print("parity SKIPPED: execution mode changed mid-run")
             return
-        checked = 0
-        for r in reqs:
-            o = outs[r.rid]
-            if o.finished < 0:
-                continue
-            with eng._scope():
-                ref = greedy_generate(
-                    api, params, r.as_batch(), steps=r.max_new_tokens,
-                    cache_len=eng.cache_len,
-                    prompt_bucket=eng.bucket_for(r.prompt_len))
-            assert np.array_equal(np.asarray(o.tokens),
-                                  np.asarray(ref[0])), (
-                f"request {r.rid} diverged from greedy oracle")
-            checked += 1
+        checked, bad = parity_mismatches(eng, api, reqs, outs)
+        assert not bad, ("request {} diverged from greedy oracle at token "
+                         "{} (engine {}, oracle {})".format(*bad[0]))
         print(f"parity OK: {checked} completed requests token-identical "
               "to greedy_generate")
 
 
-def main(argv=None) -> None:
+def parity_mismatches(engine: ServeEngine, api, reqs, outs):
+    """Replay every finished request through the batch-1
+    ``greedy_generate`` oracle, on the engine's own placed params and
+    under its Mode scope: prefill through the engine's jitted prefill (the
+    same padded computation it admitted with), decode through a fresh
+    batch-1 jit traced here, inside the scope.  Returns ``(checked,
+    mismatches)``: each mismatch is ``(rid, index of the first token that
+    differs, engine token, oracle token)`` (a token of -1 where one side
+    ended early)."""
+    checked, bad = 0, []
+    with engine._scope():
+        fns = (engine._fns()[0],
+               jax.jit(lambda p, c, t: api.decode_step(p, c, t)))
+        for r in reqs:
+            o = outs.get(r.rid)
+            if o is None or o.finished < 0:
+                continue
+            ref = np.asarray(greedy_generate(
+                api, engine.params, r.as_batch(), steps=r.max_new_tokens,
+                cache_len=engine.cache_len,
+                prompt_bucket=engine.bucket_for(r.prompt_len), fns=fns)[0])
+            got = np.asarray(o.tokens)
+            checked += 1
+            if not np.array_equal(got, ref):
+                n = min(len(got), len(ref))
+                diff = np.flatnonzero(got[:n] != ref[:n])
+                at = int(diff[0]) if diff.size else n
+                bad.append((r.rid, at,
+                            int(got[at]) if at < len(got) else -1,
+                            int(ref[at]) if at < len(ref) else -1))
+    return checked, bad
+
+
+def build_parser() -> argparse.ArgumentParser:
+    """The serving CLI — ``main``'s and ``chip_smoke.py``'s one parser."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b")
     ap.add_argument("--reduced", action="store_true")
@@ -236,6 +261,9 @@ def main(argv=None) -> None:
                          ".to_json): the file sets the baseline; CLI flags "
                          "set to non-default values override it")
     ap.add_argument("--slots", type=int, default=4)
+    ap.add_argument("--cache-len", type=int, default=None,
+                    help="KV arena length per slot (default: longest "
+                         "prompt + longest generation + 1)")
     ap.add_argument("--page-size", type=int, default=None,
                     help="activate the paged KV arena (DESIGN.md Section "
                          "14): power-of-two tokens per page; default keeps "
@@ -355,6 +383,28 @@ def main(argv=None) -> None:
                     help="assert the router stayed bounded: "
                          "max_queue_depth <= --queue-bound and shed "
                          "count > 0 (the CI overload stage)")
+    return ap
+
+
+@dataclasses.dataclass
+class Prepared:
+    """What ``main`` serves from: the parsed flags and resolved engine
+    config, the model, its params (block-pruned and compacted when
+    ``--sparsity`` asks, placed in the serving layout when ``--mesh``
+    asks), the kernel plan and the request trace."""
+    args: argparse.Namespace
+    econf: EngineConfig
+    cfg: object
+    api: object
+    params: object
+    fam_plan: object
+    mesh: object
+    reqs: list
+
+
+def prepare(argv=None) -> Prepared:
+    """Parse ``argv`` and build everything up to the engine."""
+    ap = build_parser()
     args = ap.parse_args(argv)
     econf = EngineConfig.from_args(
         args, defaults={d: ap.get_default(d) for d in vars(args)})
@@ -381,7 +431,9 @@ def main(argv=None) -> None:
         cfg = cfg.reduced()
     api = build_model(cfg)
     mesh = plan_mesh(len(jax.devices()), args.model_parallel)
-    params = api.init(jax.random.PRNGKey(0))
+    key = jax.random.PRNGKey(0)
+    params = (init_params_sharded(api, serve_mesh(econf.mesh), key)
+              if econf.mesh else api.init(key))
 
     fam_plan = None
     if args.plan:
@@ -412,7 +464,15 @@ def main(argv=None) -> None:
                            length_dist=args.length_dist, max_gen=max_gen,
                            priorities=_lens(args.priorities),
                            deadline_slack=slack_slo, ttft_deadline=ttft_slo)
+    return Prepared(args, econf, cfg, api, params, fam_plan, mesh, reqs)
 
+
+def main(argv=None) -> None:
+    enable_compile_cache()
+    prep = prepare(argv)
+    args, econf, cfg, api, params, fam_plan, mesh, reqs = (
+        prep.args, prep.econf, prep.cfg, prep.api, prep.params,
+        prep.fam_plan, prep.mesh, prep.reqs)
     if args.replicas > 0:
         _run_router(api, params, args, mesh, cfg, fam_plan, reqs,
                     econf=econf)
@@ -482,15 +542,9 @@ def main(argv=None) -> None:
             print("parity SKIPPED: execution mode changed mid-run "
                   f"({[(s, m.value) for s, m in engine.mode_history]})")
             return
-        for r in reqs:
-            with engine._scope():
-                ref = greedy_generate(
-                    api, params, r.as_batch(), steps=r.max_new_tokens,
-                    cache_len=engine.cache_len,
-                    prompt_bucket=engine.bucket_for(r.prompt_len))
-            assert np.array_equal(np.asarray(outs[r.rid].tokens),
-                                  np.asarray(ref[0])), (
-                f"request {r.rid} diverged from greedy oracle")
+        _, bad = parity_mismatches(engine, api, reqs, outs)
+        assert not bad, ("request {} diverged from greedy oracle at token "
+                         "{} (engine {}, oracle {})".format(*bad[0]))
         print(f"parity OK: all {len(reqs)} requests token-identical to "
               "greedy_generate (bucketed prompts, decode_chunk="
               f"{args.decode_chunk})")

@@ -255,9 +255,27 @@ def write_kv_slot(cache: jax.Array, update: jax.Array, slot: jax.Array
     return jax.lax.dynamic_update_slice(cache, update, (0, slot, 0, 0))
 
 
+def write_kv_layer(cache: jax.Array, layer: jax.Array, update: jax.Array,
+                   slot: jax.Array) -> jax.Array:
+    """``write_kv_slot`` into layer ``layer`` of a layer-stacked
+    (L, B, S, ...) cache.  The decode layer loop carries the whole stacked
+    cache and writes each layer's one-token update into it in place: a
+    loop that instead re-emits every layer's slice as a fresh output holds
+    several copies of the cache at once, which at 8 slots x 2048 tokens of
+    a 24-layer model no longer fits one 16 GB chip."""
+    if slot.ndim:
+        # one scatter over (layer, row, slot): a vmap over the batch axis
+        # would move it to the front and transpose the whole cache
+        rows = jnp.arange(update.shape[0])
+        return cache.at[layer, rows, slot].set(update[:, 0])
+    return jax.lax.dynamic_update_slice(cache, update[None],
+                                        (layer, 0, slot, 0, 0))
+
+
 def paged_write(pool: jax.Array, scale: Optional[jax.Array],
                 pages: jax.Array, update: jax.Array, pos: jax.Array,
-                page_size: int) -> Tuple[jax.Array, Optional[jax.Array]]:
+                page_size: int, layer: Optional[jax.Array] = None
+                ) -> Tuple[jax.Array, Optional[jax.Array]]:
     """Write a one-token K/V update into a paged pool (DESIGN.md Sec. 14).
 
     ``pool``: (num_pages, page_size, ...) shared physical pages;
@@ -269,7 +287,9 @@ def paged_write(pool: jax.Array, scale: Optional[jax.Array],
     DUMP page (id 0), which is never read, so their garbage writes are
     discarded by construction.  When ``scale`` is given the pool is int8:
     the row is quantized on the way in (optim.compression.quantize_rows)
-    and its per-token scale stored alongside.
+    and its per-token scale stored alongside.  ``layer`` addresses one
+    layer of layer-stacked (L, num_pages, ...) pools, written in place
+    (``write_kv_layer``).
     """
     from ..optim.compression import quantize_rows
     B, maxp = pages.shape
@@ -278,15 +298,17 @@ def paged_write(pool: jax.Array, scale: Optional[jax.Array],
     pid = jnp.take_along_axis(pages, (slot // page_size)[:, None],
                               axis=1)[:, 0]
     off = slot % page_size
+    idx = (pid, off) if layer is None else (layer, pid, off)
     row = update[:, 0]
     if scale is not None:
         q, s = quantize_rows(row, 1)
-        return pool.at[pid, off].set(q), scale.at[pid, off].set(s)
-    return pool.at[pid, off].set(row.astype(pool.dtype)), None
+        return pool.at[idx].set(q), scale.at[idx].set(s)
+    return pool.at[idx].set(row.astype(pool.dtype)), None
 
 
 def paged_view(pool: jax.Array, scale: Optional[jax.Array],
-               pages: jax.Array, dtype: Any) -> jax.Array:
+               pages: jax.Array, dtype: Any,
+               layer: Optional[jax.Array] = None) -> jax.Array:
     """Gather each row's pages into a (B, max_pages * page_size, ...) view.
 
     The engine rounds ``cache_len`` up to ``max_pages * page_size``, so
@@ -294,11 +316,13 @@ def paged_view(pool: jax.Array, scale: Optional[jax.Array],
     ``decode_attention``'s position mask then sees identical shapes and
     fp32 paged decode is bit-identical to the fixed arena (masked entries
     contribute an exact 0.0 either way).  int8 pools dequantize through
-    the per-token scales on the way out.
+    the per-token scales on the way out.  ``layer`` gathers from one layer
+    of layer-stacked pools.
     """
-    v = pool[pages]                      # (B, max_pages, page_size, ...)
+    idx = pages if layer is None else (layer, pages)
+    v = pool[idx]                        # (B, max_pages, page_size, ...)
     if scale is not None:
-        s = scale[pages]
+        s = scale[idx]
         v = v.astype(jnp.float32) * s[(...,) + (None,) * (v.ndim - 3)]
     B, maxp, ps = v.shape[:3]
     return v.reshape(B, maxp * ps, *v.shape[3:]).astype(dtype)
@@ -356,14 +380,17 @@ def stack_layers(init_one: Callable[[jax.Array], Params], key: jax.Array,
     """Initialize n layers and stack each leaf along a leading axis, the
     layout ``lax.scan`` consumes.  n == 0 yields empty-stacked leaves (scan
     over length-0 xs is a no-op), so irregular depth patterns degrade
-    gracefully in reduced configs."""
+    gracefully in reduced configs.
+
+    The layers are one ``vmap`` over their keys — the same values as
+    initializing them one by one and stacking, but one layer's worth of
+    program: a jitted init (the sharded four-chip path) otherwise traces
+    and compiles every layer separately."""
     if n == 0:
         proto = jax.eval_shape(init_one, key)
         return jax.tree.map(
             lambda x: jnp.zeros((0,) + x.shape, x.dtype), proto)
-    keys = jax.random.split(key, n)
-    layers = [init_one(k) for k in keys]
-    return jax.tree.map(lambda *xs: jnp.stack(xs, axis=0), *layers)
+    return jax.vmap(init_one)(jax.random.split(key, n))
 
 
 def layer_scan(use_scan: bool, body: Callable, carry, xs):

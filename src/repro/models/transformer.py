@@ -26,7 +26,7 @@ from ..configs.base import ModelConfig
 from .attention import attention, decode_attention
 from .common import (act_fn, dense_init, griffin_linear, layer_scan,
                      length_mask, paged_view, paged_write, remat_fn,
-                     rms_norm, rope, stack_layers, take_last, write_kv_slot)
+                     rms_norm, rope, stack_layers, take_last, write_kv_layer)
 from .moe import init_moe, moe_ffn
 
 Params = Dict[str, Any]
@@ -129,10 +129,12 @@ def block_train(cfg: ModelConfig, p: Params, x: jax.Array,
     return (x, aux, (k, v)) if return_kv else (x, aux)
 
 
-def block_decode(cfg: ModelConfig, p: Params, x: jax.Array, k_cache, v_cache,
-                 pos, cache_len: int):
-    """One-token block against a (B, S_cache, KVH, hd) cache; returns the
-    updated cache slices.  Sliding-window archs use a rolling cache.
+def block_decode(cfg: ModelConfig, p: Params, x: jax.Array, k_all, v_all,
+                 layer, pos, cache_len: int):
+    """One-token block of layer ``layer`` against the layer-stacked
+    (L, B, S_cache, KVH, hd) caches; writes this layer's new K/V into them
+    in place (``write_kv_layer``) and returns them updated.  Sliding-window
+    archs use a rolling cache.
 
     ``pos`` is a scalar (lockstep batch, greedy_generate) or a (B,) vector
     of per-row positions (continuous-batching slot pools,
@@ -145,22 +147,24 @@ def block_decode(cfg: ModelConfig, p: Params, x: jax.Array, k_cache, v_cache,
                    positions=pos[:, None] if per_slot else pos[None])
     rolling = cfg.window is not None and cache_len <= cfg.window
     slot = jnp.where(rolling, pos % cache_len, jnp.minimum(pos, cache_len - 1))
-    k_cache = write_kv_slot(k_cache, k, slot)
-    v_cache = write_kv_slot(v_cache, v, slot)
+    k_all = write_kv_layer(k_all, layer, k, slot)
+    v_all = write_kv_layer(v_all, layer, v, slot)
     # valid length: rolling caches become fully valid once wrapped
     eff_pos = jnp.where(rolling, jnp.minimum(pos, cache_len - 1), pos)
     win = None if rolling else cfg.window
-    o = decode_attention(q, k_cache, v_cache, eff_pos, window=win)
+    o = decode_attention(q, k_all[layer], v_all[layer], eff_pos, window=win)
     B = x.shape[0]
     x = x + griffin_linear(o.reshape(B, 1, -1), p["wo"]).astype(x.dtype)
     h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
     f, _ = _ffn(cfg, p, h2, decode=True)
-    return (x + f).astype(x.dtype), k_cache, v_cache
+    return (x + f).astype(x.dtype), k_all, v_all
 
 
 def block_decode_paged(cfg: ModelConfig, p: Params, x: jax.Array, k_pool,
-                       v_pool, k_scale, v_scale, pages, pos, page_size: int):
-    """One-token block against paged KV pools (runtime/paging.py).
+                       v_pool, k_scale, v_scale, pages, layer, pos,
+                       page_size: int):
+    """One-token block of layer ``layer`` against the layer-stacked paged
+    KV pools (runtime/paging.py), written in place like ``block_decode``.
 
     Paging only activates when the arch has no effective sliding window at
     this cache length (discovery rule in runtime/paging.py), so the fixed
@@ -172,10 +176,12 @@ def block_decode_paged(cfg: ModelConfig, p: Params, x: jax.Array, k_pool,
     per_slot = pos.ndim > 0
     q, k, v = _qkv(cfg, p, h,
                    positions=pos[:, None] if per_slot else pos[None])
-    k_pool, k_scale = paged_write(k_pool, k_scale, pages, k, pos, page_size)
-    v_pool, v_scale = paged_write(v_pool, v_scale, pages, v, pos, page_size)
-    kc = paged_view(k_pool, k_scale, pages, x.dtype)
-    vc = paged_view(v_pool, v_scale, pages, x.dtype)
+    k_pool, k_scale = paged_write(k_pool, k_scale, pages, k, pos, page_size,
+                                  layer)
+    v_pool, v_scale = paged_write(v_pool, v_scale, pages, v, pos, page_size,
+                                  layer)
+    kc = paged_view(k_pool, k_scale, pages, x.dtype, layer)
+    vc = paged_view(v_pool, v_scale, pages, x.dtype, layer)
     o = decode_attention(q, kc, vc, pos, window=None)
     B = x.shape[0]
     x = x + griffin_linear(o.reshape(B, 1, -1), p["wo"]).astype(x.dtype)
@@ -272,13 +278,15 @@ def decode_step(cfg: ModelConfig, params: Params, cache: Params,
         return _decode_step_paged(cfg, params, cache, x, pos)
     clen = cache["k"].shape[2]
 
-    def body(x, xs):
-        lp, kc, vc = xs
-        x, kc, vc = block_decode(cfg, lp, x, kc, vc, pos, clen)
-        return x, (kc, vc)
+    def body(carry, lp):
+        x, ks, vs, i = carry
+        x, ks, vs = block_decode(cfg, lp, x, ks, vs, i, pos, clen)
+        return (x, ks, vs, i + 1), None
 
-    x, (ks, vs) = layer_scan(cfg.scan_layers, body, x,
-                             (params["layers"], cache["k"], cache["v"]))
+    (x, ks, vs, _), _ = layer_scan(
+        cfg.scan_layers, body,
+        (x, cache["k"], cache["v"], jnp.zeros((), jnp.int32)),
+        params["layers"])
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = griffin_linear(x[:, 0], unembed(cfg, params))
     return logits, {"k": ks, "v": vs, "pos": pos}
@@ -290,25 +298,19 @@ def _decode_step_paged(cfg: ModelConfig, params: Params, cache: Params,
     page_size = cache["k"].shape[2]
     int8 = "k_scale" in cache
 
-    def body(x, xs):
-        if int8:
-            lp, kp, vp, ks_, vs_ = xs
-        else:
-            lp, kp, vp = xs
-            ks_ = vs_ = None
+    def body(carry, lp):
+        x, kp, vp, ks_, vs_, i = carry
         x, kp, vp, ks_, vs_ = block_decode_paged(
-            cfg, lp, x, kp, vp, ks_, vs_, pages, pos, page_size)
-        return x, ((kp, vp, ks_, vs_) if int8 else (kp, vp))
+            cfg, lp, x, kp, vp, ks_, vs_, pages, i, pos, page_size)
+        return (x, kp, vp, ks_, vs_, i + 1), None
 
-    xs = ((params["layers"], cache["k"], cache["v"],
-           cache["k_scale"], cache["v_scale"]) if int8
-          else (params["layers"], cache["k"], cache["v"]))
-    x, ys = layer_scan(cfg.scan_layers, body, x, xs)
+    carry = (x, cache["k"], cache["v"], cache.get("k_scale"),
+             cache.get("v_scale"), jnp.zeros((), jnp.int32))
+    (x, kp, vp, ks_, vs_, _), _ = layer_scan(cfg.scan_layers, body, carry,
+                                             params["layers"])
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = griffin_linear(x[:, 0], unembed(cfg, params))
-    out = {"pos": pos, "pages": pages}
+    out = {"pos": pos, "pages": pages, "k": kp, "v": vp}
     if int8:
-        out["k"], out["v"], out["k_scale"], out["v_scale"] = ys
-    else:
-        out["k"], out["v"] = ys
+        out["k_scale"], out["v_scale"] = ks_, vs_
     return logits, out

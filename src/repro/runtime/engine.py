@@ -610,6 +610,11 @@ class ServeEngine:
         config = resolve_engine_config(config, legacy, type(self).__name__)
         self.config = config
         self.api = api
+        # one placement up front: compacted weights arrive as host numpy
+        # (kernels.griffin_spmm.preprocess_weights) and would otherwise be
+        # copied to the device on every jitted call; already-placed arrays
+        # (the mesh engine's sharded params) are left where they are
+        params = jax.device_put(params)
         self.params = params
         if config.arena.cache_len is None:
             raise ValueError("cache_len is required: set "

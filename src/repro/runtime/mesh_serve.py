@@ -23,7 +23,7 @@ to the single-device engine on the same trace, for all four execution
 Modes.  Because no GEMM's contraction dim is ever split, each device's
 share of every matmul is fully local, and ``models.common.griffin_linear``
 runs the *real* Pallas kernels on every mesh size by wrapping them in
-``jax.experimental.shard_map`` with zero in-kernel collectives — each
+``jax.shard_map`` with zero in-kernel collectives — each
 device executes ``griffin_matmul_shard``/``sparse_a_matmul_shard``/
 ``dense_matmul_shard`` on its N-slice (DESIGN.md Section 10).  The former
 jnp fallbacks (``griffin_matmul(spmd=True)`` decompaction, plain sharded
@@ -98,6 +98,16 @@ def serve_shardings(api: ModelApi, mesh: Mesh, params: Any, num_slots: int,
     c_sh = shard_cache(arena, mesh, num_slots, decode=True,
                        heads=cache_heads(api), paged=pset)
     return p_sh, c_sh, NamedSharding(mesh, P())
+
+
+def init_params_sharded(api: ModelApi, mesh: Mesh, key: jax.Array) -> Any:
+    """``api.init`` jitted straight into the serving layout
+    (``serve_shardings``' parameter tree): every device generates only its
+    own shard, so a model larger than one chip is never whole on any
+    device — the four-chip path's precondition."""
+    p_sh = shard_params(jax.eval_shape(api.init, key), mesh, fsdp=False,
+                        serve=True)
+    return jax.jit(api.init, out_shardings=p_sh)(key)
 
 
 def mesh_serve_fns(api: ModelApi, mesh: Mesh, params: Any, num_slots: int,

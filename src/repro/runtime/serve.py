@@ -172,7 +172,8 @@ def _dp(mesh: Mesh) -> int:
 
 
 def greedy_generate(api: ModelApi, params, batch: Dict, steps: int,
-                    cache_len: int, prompt_bucket: Optional[int] = None):
+                    cache_len: int, prompt_bucket: Optional[int] = None,
+                    fns: Optional[Tuple[Callable, Callable]] = None):
     """Reference generation loop, one static batch in lockstep — the parity
     oracle for the continuous-batching engine (``runtime.engine``): per-slot
     decode is row-wise independent, so the engine's tokens for a request
@@ -183,11 +184,23 @@ def greedy_generate(api: ModelApi, params, batch: Dict, steps: int,
     ``engine.bucket_for(prompt_len)``): the prompt is right-padded to the
     bucket with lengths threaded, so the oracle runs the *same padded
     computation* the engine admitted the request with — the definition of
-    token parity under bucketing (DESIGN.md Section 9)."""
+    token parity under bucketing (DESIGN.md Section 9).
+
+    ``fns``: jitted ``(prefill(params, batch), decode_step(params, cache,
+    token))`` to run the loop with — e.g. an engine's prefill jit and a
+    batch-1 decode jit traced under its Mode scope — so the oracle
+    compiles once per shape instead of once per step (an eager
+    ``lax.scan`` over the layers retraces and recompiles on every call,
+    which at published widths costs seconds per token).  Default: the
+    eager model functions."""
     batch = pad_prompt_batch(batch, prompt_bucket)
-    cache, logits = api.prefill(params, batch, cache_len=cache_len)
+    if fns is None:
+        fns = (lambda p, b: api.prefill(p, b, cache_len=cache_len),
+               api.decode_step)
+    prefill_fn, decode_fn = fns
+    cache, logits = prefill_fn(params, batch)
     toks = [jnp.argmax(logits, -1).astype(jnp.int32)[:, None]]
     for _ in range(steps - 1):
-        logits, cache = api.decode_step(params, cache, toks[-1])
+        logits, cache = decode_fn(params, cache, toks[-1])
         toks.append(jnp.argmax(logits, -1).astype(jnp.int32)[:, None])
     return jnp.concatenate(toks, axis=1)

@@ -46,7 +46,10 @@ def block_prune(w: jax.Array, sparsity: float, block_k: int = 128,
         return w
     k, n = w.shape
     pk, pn = -(-k // block_k) * block_k, -(-n // unit) * unit
-    wp = jnp.zeros((pk, pn), w.dtype).at[:k, :n].set(w)
+    # aligned weights (every published width) skip the padded copy, which
+    # would also land a sharded weight whole on the default device
+    wp = w if (pk, pn) == (k, n) else \
+        jnp.zeros((pk, pn), w.dtype).at[:k, :n].set(w)
     nb_k, nb_n = pk // block_k, pn // unit
     blocks = wp.reshape(nb_k, block_k, nb_n, unit)
     norms = jnp.sqrt((blocks.astype(jnp.float32) ** 2).sum(axis=(1, 3)))

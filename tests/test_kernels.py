@@ -68,6 +68,39 @@ def test_dual_skips_zero_a_blocks_exactly():
     np.testing.assert_array_equal(np.asarray(out_b), np.asarray(out_ab))
 
 
+def test_dual_zero_test_bf16_matches_single_mode():
+    """The dual kernel's zero test widens each bf16 A tile to f32 before
+    comparing (Mosaic cannot reduce a bf16 compare's packed i1 mask).  In
+    bf16 the predicate must still skip exactly the all-zero tiles — here
+    one all-zero K tile, one live tile, one tile of -0.0 (zero) and one
+    whose only nonzero is the smallest normal bf16 (live) — and the
+    output must be bit-equal to the single-mode kernel."""
+    rng = np.random.RandomState(5)
+    k = 4 * 128
+    a = rng.randn(16, k).astype(np.float32)
+    a[:, 0:128] = 0.0                          # all-zero tile
+    a[:, 256:384] = -0.0                       # signed zeros: still zero
+    a[:, 384:512] = 0.0
+    # row 3 lives only through the barely-live tile, so skipping that tile
+    # would zero the row
+    a[3, :] = 0.0
+    a[3, 400] = float(jnp.finfo(jnp.bfloat16).tiny)
+    a = jnp.asarray(a, jnp.bfloat16)
+    w = block_prune(jnp.asarray(rng.randn(k, 256), jnp.bfloat16), 0.5,
+                    block_k=128, unit=32)
+    gw = preprocess_weights(np.asarray(w), block_k=128, block_n=128,
+                            unit=32, balance=False)
+    out_b = griffin_matmul(a, gw, dual=False, interpret=True)
+    out_ab = griffin_matmul(a, gw, dual=True, interpret=True)
+    assert out_ab.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(np.asarray(out_ab.astype(jnp.float32)),
+                                  np.asarray(out_b.astype(jnp.float32)))
+    assert np.asarray(out_ab[3].astype(jnp.float32)).any()
+    ref = np.asarray(a, np.float32) @ np.asarray(w, np.float32)
+    np.testing.assert_allclose(np.asarray(out_ab, np.float32), ref,
+                               rtol=2e-2, atol=2e-2)
+
+
 def test_balancing_reduces_grid_depth_on_clustered_patterns():
     """Channel-clustered pruning (the realistic case, cf. MaskModel) gives
     the shuffle analogue something to balance."""

@@ -75,3 +75,48 @@ def test_set_host_device_count(monkeypatch):
         # silently — the flag still lands for child processes
         with pytest.warns(UserWarning, match="next process"):
             plat.set_host_device_count(64)
+
+
+def test_interpret_follows_the_backend_not_the_override(monkeypatch):
+    """GRIFFIN_PLATFORM picks what ``set_platform`` pins; it never decides
+    how kernels lower on a backend that is already running.  On a TPU
+    backend no override turns interpret mode on, and an explicit request
+    for it is refused."""
+    monkeypatch.setenv("GRIFFIN_PLATFORM", "cpu")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert plat.kernel_lowering() == "mosaic"
+    assert not plat.kernel_interpret()
+    assert plat.checked_interpret(False) is False
+    with pytest.raises(RuntimeError, match="TPU backend"):
+        plat.checked_interpret(True)
+    # and the other way round: a CPU backend interprets whatever the
+    # override says
+    monkeypatch.setenv("GRIFFIN_PLATFORM", "tpu")
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert plat.kernel_interpret()
+    assert plat.checked_interpret(True) is True
+
+
+def test_compile_cache_env_directory_is_left_alone(monkeypatch, tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, JAX reads it itself: the helper
+    reports it and changes nothing."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
+    assert plat.enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_default_is_one_fixed_checkout_path(monkeypatch):
+    """Unset, the cache goes to the same in-checkout path on every call
+    (the path is part of the cache key), and that path is git-ignored."""
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        first = plat.enable_compile_cache()
+        assert plat.enable_compile_cache() == first
+        assert first == str(plat.REPO_ROOT / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == first
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    ignored = (plat.REPO_ROOT / ".gitignore").read_text().splitlines()
+    assert ".jax_cache/" in ignored

@@ -56,14 +56,13 @@ def test_compressed_psum_matches_mean():
     g = {"a": jnp.asarray(np.random.RandomState(0).randn(32).astype(np.float32))}
     err = init_error(g)
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def f(gg, ee):
         return compressed_psum_tree(gg, ee, mesh, ("data",))
 
-    red, new_err = shard_map(f, mesh=mesh, in_specs=(P(), P()),
-                             out_specs=(P(), P()))(g, err)
+    red, new_err = jax.shard_map(f, mesh=mesh, in_specs=(P(), P()),
+                                 out_specs=(P(), P()))(g, err)
     # single shard: mean == dequantized self; error = quantization residual
     np.testing.assert_allclose(np.asarray(red["a"]), np.asarray(g["a"]),
                                atol=float(jnp.abs(g["a"]).max()) / 100)
