@@ -1,0 +1,6 @@
+"""Kernels: griffin_spmm's share of its roofline over the decode steps."""
+from ._roofline import share
+
+
+def read(run, trace):
+    return share(run, trace, "griffin_spmm")
