@@ -50,6 +50,7 @@ def dense_matmul_kernel(a: jax.Array, b: jax.Array, *, block_m: int,
     grid = (m // block_m, n // block_n, nk)
     return pl.pallas_call(
         functools.partial(_matmul_kernel, nk=nk),
+        name="dense_gemm",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda i, j, kk: (i, kk)),

@@ -90,6 +90,7 @@ def griffin_spmm_kernel(a: jax.Array, b_comp: jax.Array, kidx: jax.Array,
     out_dtype = out_dtype or a.dtype
     return pl.pallas_call(
         functools.partial(_spmm_kernel, nkc=max_cnt, dual=dual),
+        name="griffin_spmm",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=grid,

@@ -76,6 +76,7 @@ def sparse_a_gemm_kernel(a: jax.Array, b: jax.Array, kidx: jax.Array,
     out_dtype = out_dtype or a.dtype
     return pl.pallas_call(
         functools.partial(_sparse_a_kernel, nkc=max_cnt),
+        name="sparse_a",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=grid,

@@ -145,6 +145,7 @@ def _replicated(x: jax.Array, mesh) -> jax.Array:
         x, NamedSharding(mesh, PartitionSpec()))
 
 
+@jax.named_scope("griffin_linear")
 def griffin_linear(x: jax.Array, w) -> jax.Array:
     """The weight GEMM of the model stack: ``x @ w`` morphed per call.
 
@@ -171,7 +172,9 @@ def griffin_linear(x: jax.Array, w) -> jax.Array:
     ``configs.platform.kernel_interpret``, since mesh jit sets are traced
     after placement).
 
-    Leading batch/sequence axes are flattened into the GEMM M axis.
+    Leading batch/sequence axes are flattened into the GEMM M axis.  The
+    whole call runs under the name scope ``griffin_linear``, so the ops it
+    lowers to carry it in their op-name metadata.
     """
     ctx = _EXEC_STACK[-1]
     mesh = ctx.spmd_mesh

@@ -119,8 +119,9 @@ def block_train(cfg: ModelConfig, p: Params, x: jax.Array,
     capacity)."""
     h = rms_norm(x, p["ln1"], cfg.norm_eps)
     q, k, v = _qkv(cfg, p, h, positions)
-    o = attention(q, k, v, causal=True, window=cfg.window,
-                  kv_chunk=cfg.kv_chunk)
+    with jax.named_scope("attention"):
+        o = attention(q, k, v, causal=True, window=cfg.window,
+                      kv_chunk=cfg.kv_chunk)
     B, S, _, _ = q.shape
     x = x + griffin_linear(o.reshape(B, S, -1), p["wo"]).astype(x.dtype)
     h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
@@ -146,13 +147,16 @@ def block_decode(cfg: ModelConfig, p: Params, x: jax.Array, k_all, v_all,
     q, k, v = _qkv(cfg, p, h,
                    positions=pos[:, None] if per_slot else pos[None])
     rolling = cfg.window is not None and cache_len <= cfg.window
-    slot = jnp.where(rolling, pos % cache_len, jnp.minimum(pos, cache_len - 1))
-    k_all = write_kv_layer(k_all, layer, k, slot)
-    v_all = write_kv_layer(v_all, layer, v, slot)
-    # valid length: rolling caches become fully valid once wrapped
-    eff_pos = jnp.where(rolling, jnp.minimum(pos, cache_len - 1), pos)
-    win = None if rolling else cfg.window
-    o = decode_attention(q, k_all[layer], v_all[layer], eff_pos, window=win)
+    with jax.named_scope("attention"):
+        slot = jnp.where(rolling, pos % cache_len,
+                         jnp.minimum(pos, cache_len - 1))
+        k_all = write_kv_layer(k_all, layer, k, slot)
+        v_all = write_kv_layer(v_all, layer, v, slot)
+        # valid length: rolling caches become fully valid once wrapped
+        eff_pos = jnp.where(rolling, jnp.minimum(pos, cache_len - 1), pos)
+        win = None if rolling else cfg.window
+        o = decode_attention(q, k_all[layer], v_all[layer], eff_pos,
+                             window=win)
     B = x.shape[0]
     x = x + griffin_linear(o.reshape(B, 1, -1), p["wo"]).astype(x.dtype)
     h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
@@ -176,13 +180,14 @@ def block_decode_paged(cfg: ModelConfig, p: Params, x: jax.Array, k_pool,
     per_slot = pos.ndim > 0
     q, k, v = _qkv(cfg, p, h,
                    positions=pos[:, None] if per_slot else pos[None])
-    k_pool, k_scale = paged_write(k_pool, k_scale, pages, k, pos, page_size,
-                                  layer)
-    v_pool, v_scale = paged_write(v_pool, v_scale, pages, v, pos, page_size,
-                                  layer)
-    kc = paged_view(k_pool, k_scale, pages, x.dtype, layer)
-    vc = paged_view(v_pool, v_scale, pages, x.dtype, layer)
-    o = decode_attention(q, kc, vc, pos, window=None)
+    with jax.named_scope("attention"):
+        k_pool, k_scale = paged_write(k_pool, k_scale, pages, k, pos,
+                                      page_size, layer)
+        v_pool, v_scale = paged_write(v_pool, v_scale, pages, v, pos,
+                                      page_size, layer)
+        kc = paged_view(k_pool, k_scale, pages, x.dtype, layer)
+        vc = paged_view(v_pool, v_scale, pages, x.dtype, layer)
+        o = decode_attention(q, kc, vc, pos, window=None)
     B = x.shape[0]
     x = x + griffin_linear(o.reshape(B, 1, -1), p["wo"]).astype(x.dtype)
     h2 = rms_norm(x, p["ln2"], cfg.norm_eps)

@@ -59,6 +59,7 @@ from .config import EngineConfig, resolve_engine_config
 from .fault import DeviceLoss, FaultInjector
 from .paging import PageAllocator, PagedSpec, build_spec, paged_tree
 from .serve import make_chunk_ladder, pad_prompt_batch
+from .spans import span
 from .straggler import StragglerDetector
 
 # Category knob handed to the sparse_execution scope when the *measured*
@@ -139,6 +140,13 @@ class RequestOutput:
     token_steps: List[int] = dataclasses.field(default_factory=list)
     attribution: Attribution = Attribution.NORMAL
     shed_reason: Optional[str] = None
+    # host wall stamps (time.perf_counter): when ``engine.add`` was called,
+    # when the scheduler admitted the request (before its prefill
+    # dispatch), and when the tick's sync returned its first token
+    t_added: Optional[float] = dataclasses.field(default=None, compare=False)
+    t_admitted: Optional[float] = dataclasses.field(default=None,
+                                                    compare=False)
+    t_first: Optional[float] = dataclasses.field(default=None, compare=False)
 
 
 # ---------------------------------------------------------------------------
@@ -558,6 +566,7 @@ class EngineSnapshot:
     mode_history: List[Tuple[int, Mode]]
     stats: Dict[str, int]
     prefill_buckets: set
+    t_added: Dict[int, float]
     ckpt_step: Optional[int] = None
     # paged-arena host state (allocator free list, slot->pages map, dirty
     # slots pending reclamation) — the device-side pool/page-table/scale
@@ -694,9 +703,15 @@ class ServeEngine:
         self._since_measure = 0
         self.outputs: Dict[int, RequestOutput] = {}
         self.events: List[Tuple[int, int, int]] = []    # (step, rid, token)
+        # live_rows: live row-steps of the decode chunks (the chunk's
+        # on-device live count, so emitted == prefill_calls + live_rows);
+        # prefill_tokens / prefill_padded_tokens: prompt tokens prefilled
+        # and the bucket lengths they ran at
         self.stats = {"decode_steps": 0, "prefill_calls": 0, "emitted": 0,
-                      "idle_steps": 0, "retraces": 0, "chunk_calls": 0,
-                      "host_syncs": 0}
+                      "retraces": 0, "chunk_calls": 0, "host_syncs": 0,
+                      "live_rows": 0, "prefill_tokens": 0,
+                      "prefill_padded_tokens": 0}
+        self._t_added: Dict[int, float] = {}    # rid -> wall stamp of add
         self.prefill_buckets: set = set()       # distinct admitted shapes
         # prompt buckets longer than the usable cache window cannot be
         # right-padded (the window would evict real K/V); those prompts
@@ -901,6 +916,7 @@ class ServeEngine:
             raise ValueError(f"request {req.rid}: enc-dec model needs "
                              "extras['frames']")
         self.sched.add(req)
+        self._t_added[req.rid] = time.perf_counter()
 
     def bucket_for(self, prompt_len: int) -> Optional[int]:
         """Power-of-two prefill bucket for a prompt length (min
@@ -956,16 +972,54 @@ class ServeEngine:
     def _prefill(self, req: Request):
         prefill_fn = self._fns()[0]
         bucket = self.bucket_for(req.prompt_len)
-        batch = req.as_batch(bucket)
-        self.prefill_buckets.add(batch["tokens"].shape[-1])
-        with self._scope():
-            cache1, logits = prefill_fn(self.params, batch)
+        with span("prefill", rid=req.rid, prompt_len=req.prompt_len,
+                  bucket=bucket or req.prompt_len):
+            batch = req.as_batch(bucket)
+            with self._scope():
+                cache1, logits = prefill_fn(self.params, batch)
+        padded = batch["tokens"].shape[-1]
+        self.prefill_buckets.add(padded)
         self.stats["prefill_calls"] += 1
+        self.stats["prefill_tokens"] += req.prompt_len
+        self.stats["prefill_padded_tokens"] += padded
         return cache1, logits
+
+    def _admit(self) -> List[Tuple[int, Request]]:
+        """This tick's admissions (after reclaiming dead slots' pages), each
+        with a fresh ``RequestOutput`` stamped as admitted now."""
+        with span("admit", waiting=self.sched.waiting_count,
+                  free=len(self.sched._free)):
+            self._poll_fault("admission")
+            self._flush_dirty()
+            admitted = self.sched.admissions(self.clock,
+                                             gate=self._admission_gate())
+            now = time.perf_counter()
+            for _, req in admitted:
+                self.outputs[req.rid] = RequestOutput(
+                    req.rid, admitted=self.clock,
+                    t_added=self._t_added.pop(req.rid, None), t_admitted=now)
+        return admitted
+
+    def _insert_slot(self, slot: int, req: Request, cache1, logits):
+        """Dispatch the slot insert of a prefilled request; returns its
+        (1,) first token, still on the device."""
+        with span("insert", rid=req.rid, slot=slot):
+            rem = jnp.asarray(req.max_new_tokens - 1, jnp.int32)
+            args = (self.cache, self._tokens, self._remaining, cache1,
+                    logits, jnp.asarray(slot, jnp.int32), rem)
+            if self._paged is not None:
+                ids = self._reserved_pages.pop(req.rid)
+                self._slot_pages[slot] = ids
+                args += (jnp.asarray(self._paged.page_row(ids)),)
+            self.cache, self._tokens, self._remaining, tok = \
+                self._insert(*args)
+        return tok
 
     def _emit(self, slot: int, token: int) -> None:
         req = self.sched.running[slot]
         out = self.outputs[req.rid]
+        if not out.tokens:
+            out.t_first = time.perf_counter()
         out.tokens.append(token)
         out.token_steps.append(self.clock)
         self.events.append((self.clock, req.rid, token))
@@ -993,6 +1047,7 @@ class ServeEngine:
                 if self._paged is not None:
                     self._dirty_slots.add(slot)
                 return True
+        self._t_added.pop(rid, None)
         return self.sched.remove_waiting(rid)
 
     @property
@@ -1025,38 +1080,26 @@ class ServeEngine:
         token-identical to an uninterrupted run (DESIGN.md Section 11).
         """
         t0 = time.perf_counter()
-        if self._recovery_armed():
-            self._snapshot = self._capture()
-        impl = self._step_fused if self.fused else self._step_stepwise
-        try:
-            events = impl()
-        except DeviceLoss as loss:
-            self._recover(list(loss.lost), self._snapshot)
-            events = impl()
-        self._observe_hosts(time.perf_counter() - t0)
+        with span("tick", clock=self.clock, mode=self.mode.value):
+            if self._recovery_armed():
+                self._snapshot = self._capture()
+            impl = self._step_fused if self.fused else self._step_stepwise
+            try:
+                events = impl()
+            except DeviceLoss as loss:
+                self._recover(list(loss.lost), self._snapshot)
+                events = impl()
+            self._observe_hosts(time.perf_counter() - t0)
         return events
 
     def _step_fused(self) -> List[Tuple[int, int, int]]:
         ev_start = len(self.events)
         pending: List[Tuple[int, int, jax.Array]] = []  # slot, rid, dev tok
-        self._poll_fault("admission")
-        self._flush_dirty()
-        for slot, req in self.sched.admissions(self.clock,
-                                               gate=self._admission_gate()):
+        for slot, req in self._admit():
             cache1, logits = self._prefill(req)
             self._poll_fault("prefill")
-            rem = jnp.asarray(req.max_new_tokens - 1, jnp.int32)
-            args = (self.cache, self._tokens, self._remaining, cache1,
-                    logits, jnp.asarray(slot, jnp.int32), rem)
-            if self._paged is not None:
-                ids = self._reserved_pages.pop(req.rid)
-                self._slot_pages[slot] = ids
-                args += (jnp.asarray(self._paged.page_row(ids)),)
-            self.cache, self._tokens, self._remaining, tok = \
-                self._insert(*args)
-            self.outputs[req.rid] = RequestOutput(req.rid,
-                                                  admitted=self.clock)
-            pending.append((slot, req.rid, tok))
+            pending.append((slot, req.rid,
+                            self._insert_slot(slot, req, cache1, logits)))
         admitted = frozenset(s for s, _, _ in pending)
         if self.sched.active and all(
                 self.sched.remaining[s] - (s in admitted) <= 0
@@ -1064,41 +1107,47 @@ class ServeEngine:
             # pure-admission tick: every live slot is a fresh single-token
             # request — nothing owes a decode step, so fetch the prefill
             # tokens without dispatching a dead chunk
-            first_toks = jax.device_get([t for _, _, t in pending])
+            with span("sync"):
+                first_toks = jax.device_get([t for _, _, t in pending])
             self.stats["host_syncs"] += 1
-            for (slot, rid, _), tok in zip(pending, first_toks):
-                self._emit(slot, int(tok[0]))
-            self.clock += 1
-        elif self.sched.active:
-            chunk = self._chunk_len(admitted)
-            chunk_fn = self._fns()[2](chunk)
-            with self._scope():
-                (self.cache, self._tokens, self._remaining, ring,
-                 zf_num, zf_den) = chunk_fn(self.params, self.cache,
-                                            self._tokens, self._remaining)
-            self._poll_fault("decode")
-            ring, first_toks, zf_num, zf_den = jax.device_get(
-                (ring, [t for _, _, t in pending], zf_num, zf_den))
-            self.stats["host_syncs"] += 1
-            self.stats["chunk_calls"] += 1
-            self.stats["decode_steps"] += chunk
-            # prefill-boundary emissions first: the chunk consumed these
-            # tokens as its first feedback, so they precede the ring rows
-            for (slot, rid, _), tok in zip(pending, first_toks):
-                self._emit(slot, int(tok[0]))
-            for t in range(chunk):
-                live = self.sched.active
-                if not live:
-                    break
-                for slot in live:
-                    self._emit(slot, int(ring[t, slot]))
+            with span("emit", tokens=len(pending)):
+                for (slot, rid, _), tok in zip(pending, first_toks):
+                    self._emit(slot, int(tok[0]))
                 self.clock += 1
-            self._since_measure += chunk
-            if zf_den > 0 and self._since_measure >= self.measure_every:
-                self._measure(float(zf_num) / float(zf_den))
+        elif self.sched.active:
+            with span("chunk") as sp:
+                chunk = self._chunk_len(admitted)
+                sp.set_metadata(chunk=chunk, live=len(self.sched.running))
+                chunk_fn = self._fns()[2](chunk)
+                with self._scope():
+                    (self.cache, self._tokens, self._remaining, ring,
+                     zf_num, zf_den) = chunk_fn(self.params, self.cache,
+                                                self._tokens, self._remaining)
+                self._poll_fault("decode")
+            with span("sync"):
+                ring, first_toks, zf_num, zf_den = jax.device_get(
+                    (ring, [t for _, _, t in pending], zf_num, zf_den))
+            self.stats["host_syncs"] += 1
+            with span("emit") as sp:
+                self.stats["chunk_calls"] += 1
+                self.stats["decode_steps"] += chunk
+                self.stats["live_rows"] += int(zf_den)
+                # prefill-boundary emissions first: the chunk consumed these
+                # tokens as its first feedback, so they precede the ring rows
+                for (slot, rid, _), tok in zip(pending, first_toks):
+                    self._emit(slot, int(tok[0]))
+                for t in range(chunk):
+                    live = self.sched.active
+                    if not live:
+                        break
+                    for slot in live:
+                        self._emit(slot, int(ring[t, slot]))
+                    self.clock += 1
+                self._since_measure += chunk
+                if zf_den > 0 and self._since_measure >= self.measure_every:
+                    self._measure(float(zf_num) / float(zf_den))
+                sp.set_metadata(tokens=len(self.events) - ev_start)
         else:
-            if self.sched.waiting_count:
-                self.stats["idle_steps"] += 1
             self.clock += 1
         return self.events[ev_start:]
 
@@ -1110,23 +1159,10 @@ class ServeEngine:
         and as a behavioural reference — token output is identical to the
         fused path by construction."""
         ev_start = len(self.events)
-        self._poll_fault("admission")
-        self._flush_dirty()
-        for slot, req in self.sched.admissions(self.clock,
-                                               gate=self._admission_gate()):
+        for slot, req in self._admit():
             cache1, logits = self._prefill(req)
             self._poll_fault("prefill")
-            rem = jnp.asarray(req.max_new_tokens - 1, jnp.int32)
-            args = (self.cache, self._tokens, self._remaining, cache1,
-                    logits, jnp.asarray(slot, jnp.int32), rem)
-            if self._paged is not None:
-                ids = self._reserved_pages.pop(req.rid)
-                self._slot_pages[slot] = ids
-                args += (jnp.asarray(self._paged.page_row(ids)),)
-            self.cache, self._tokens, self._remaining, tok = \
-                self._insert(*args)
-            self.outputs[req.rid] = RequestOutput(req.rid,
-                                                  admitted=self.clock)
+            tok = self._insert_slot(slot, req, cache1, logits)
             self.stats["host_syncs"] += 1
             self._emit(slot, int(tok[0]))
         active = self.sched.active
@@ -1141,6 +1177,7 @@ class ServeEngine:
             host = np.asarray(toks)
             self.stats["host_syncs"] += 1
             self.stats["decode_steps"] += 1
+            self.stats["live_rows"] += len(active)
             self._since_measure += 1
             if self._since_measure >= self.measure_every:
                 self._measure(float(sparsity_of(
@@ -1148,8 +1185,6 @@ class ServeEngine:
                 self.stats["host_syncs"] += 1
             for slot in active:
                 self._emit(slot, int(host[slot]))
-        elif self.sched.waiting_count:
-            self.stats["idle_steps"] += 1
         self.clock += 1
         return self.events[ev_start:]
 
@@ -1181,6 +1216,7 @@ class ServeEngine:
             a_measured=self.a_measured, since_measure=self._since_measure,
             mode_history=list(self.mode_history), stats=dict(self.stats),
             prefill_buckets=set(self.prefill_buckets),
+            t_added=dict(self._t_added),
             paging=(self._paging_state() if self._paged is not None
                     else None))
         if self.snapshot_dir is not None:
@@ -1215,6 +1251,7 @@ class ServeEngine:
         self.mode_history = list(snap.mode_history)
         self.stats = dict(snap.stats)
         self.prefill_buckets = set(snap.prefill_buckets)
+        self._t_added = dict(snap.t_added)
         if self._paged is not None:
             if snap.paging is None:
                 raise RuntimeError("paged engine snapshot lacks paging state")

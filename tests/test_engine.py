@@ -566,3 +566,133 @@ def test_engine_unknown_kwarg_raises():
     params = api.init(jax.random.PRNGKey(0))
     with pytest.raises(TypeError, match="num_slotz"):
         ServeEngine(api, params, num_slotz=3)
+
+
+# ---------------------------------------------------------------------------
+# spans, counters and wall stamps (runtime/spans.py)
+# ---------------------------------------------------------------------------
+
+def _stamped_trace():
+    """A lone one-token request (a pure-admission tick), then chunked
+    decode with admissions at three other bucket lengths."""
+    return [Request(0, np.arange(1, 6, dtype=np.int32), 1, arrival=0),
+            Request(1, np.arange(1, 13, dtype=np.int32), 6, arrival=0),
+            Request(2, np.arange(1, 21, dtype=np.int32), 3, arrival=2),
+            Request(3, np.arange(1, 4, dtype=np.int32), 9, arrival=3)]
+
+
+def _stamped_engine():
+    from repro.runtime.config import ArenaConfig, EngineConfig
+    api = fake_api()
+    cfg = EngineConfig(arena=ArenaConfig(num_slots=2, cache_len=64)
+                       ).with_fields(decode_chunk=4)
+    return ServeEngine(api, api.init(jax.random.PRNGKey(0)), config=cfg)
+
+
+def _ticks(eng, reqs):
+    for r in reqs:
+        eng.add(r)
+    n = 0
+    while eng.sched.has_work():
+        eng.step()
+        n += 1
+    return n
+
+
+def _engine_spans(logdir):
+    import glob
+    import os
+    from jax.profiler import ProfileData
+    path = max(glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
+                         recursive=True), key=os.path.getmtime)
+    return [(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:") for line in plane.lines
+            for e in line.events if e.name.startswith("engine.")]
+
+
+def test_engine_spans_nest_in_each_tick(tmp_path):
+    eng = _stamped_engine()
+    with jax.profiler.trace(str(tmp_path)):
+        ticks = _ticks(eng, _stamped_trace())
+    spans = _engine_spans(str(tmp_path))
+    assert {s[0] for s in spans} == {
+        "engine." + n for n in ("tick", "admit", "prefill", "insert",
+                                "chunk", "sync", "emit")}
+    tick = sorted(s for s in spans if s[0] == "engine.tick")
+    assert len(tick) == ticks
+    assert {t[3]["mode"] for t in tick} == {eng.mode.value}
+    assert [t[3]["clock"] for t in tick] == sorted(t[3]["clock"]
+                                                   for t in tick)
+    children = [s for s in spans if s[0] != "engine.tick"]
+    for c in children:
+        # inside exactly one tick, and inside no other child
+        assert sum(t[1] <= c[1] and c[2] <= t[2] for t in tick) == 1, c
+        assert not any(o is not c and o[1] <= c[1] and c[2] <= o[2]
+                       and o[0] != c[0] for o in children), c
+    by = lambda n: [s[3] for s in children if s[0] == "engine." + n]
+    assert sorted(a["rid"] for a in by("prefill")) == [0, 1, 2, 3]
+    assert sorted(a["rid"] for a in by("insert")) == [0, 1, 2, 3]
+    assert {a["rid"]: (a["prompt_len"], a["bucket"])
+            for a in by("prefill")} == {0: (5, 8), 1: (12, 16),
+                                        2: (20, 32), 3: (3, 8)}
+    assert all(a["chunk"] in (1, 2, 4) and a["live"] >= 1
+               for a in by("chunk"))
+    assert sum(a["tokens"] for a in by("emit")) == eng.stats["emitted"]
+    # the first tick only admits the one-token request: sync and emit, no
+    # chunk
+    first = [s[0] for s in children if tick[0][1] <= s[1] < tick[0][2]]
+    assert sorted(first) == ["engine.admit", "engine.emit", "engine.insert",
+                             "engine.prefill", "engine.sync"]
+    assert len(by("sync")) == eng.stats["host_syncs"]
+
+
+def test_engine_spans_add_no_host_sync(tmp_path):
+    """The same trace, traced and not: the same tokens and counters, and
+    the seed engine's six host syncs."""
+    plain, traced = _stamped_engine(), _stamped_engine()
+    _ticks(plain, _stamped_trace())
+    with jax.profiler.trace(str(tmp_path)):
+        _ticks(traced, _stamped_trace())
+    assert plain.stats == traced.stats
+    assert plain.stats["host_syncs"] == 6
+    assert {r: o.tokens for r, o in plain.outputs.items()} == \
+        {r: o.tokens for r, o in traced.outputs.items()}
+
+
+def test_engine_counters_match_the_work():
+    eng = _stamped_engine()
+    reqs = _stamped_trace()
+    _ticks(eng, reqs)
+    st = eng.stats
+    assert st["live_rows"] == st["emitted"] - st["prefill_calls"] == 15
+    assert st["prefill_tokens"] == sum(r.prompt_len for r in reqs)
+    assert st["prefill_padded_tokens"] == \
+        sum(eng.bucket_for(r.prompt_len) for r in reqs)
+    assert "idle_steps" not in st
+
+
+def test_engine_stamps_in_order():
+    eng = _stamped_engine()
+    _ticks(eng, _stamped_trace())
+    assert sorted(eng.outputs) == [0, 1, 2, 3]
+    for o in eng.outputs.values():
+        assert None not in (o.t_added, o.t_admitted, o.t_first)
+        assert o.t_added <= o.t_admitted <= o.t_first
+
+
+def test_layer_scopes_name_the_compiled_chunk_ops():
+    """The fused decode chunk's ops carry the model's ``attention`` and
+    ``griffin_linear`` name scopes in their op-name metadata."""
+    import re
+    api = build_model(get_config("stablelm-1.6b").reduced())
+    params = jax.eval_shape(api.init, jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: jax.tree.map(
+        lambda x: jnp.zeros((2,), x.dtype) if x.ndim == 0 else x,
+        api.init_cache(2, 32)))
+    tokens = jax.ShapeDtypeStruct((2, 1), jnp.int32)
+    text = jax.jit(make_decode_chunk_fn(api, 2)).lower(
+        params, cache, tokens, tokens.update(shape=(2,))).compile().as_text()
+    names = re.findall(r'op_name="([^"]*)"', text)
+    for scope in ("attention", "griffin_linear"):
+        assert any(f"/{scope}/" in n for n in names), scope

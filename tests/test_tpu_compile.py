@@ -122,6 +122,18 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, block_m):
         _compile(fn, *shapes)
 
 
+@pytest.mark.parametrize("kernel,name", [
+    ("dense_gemm", "dense_gemm"), ("sparse_a", "sparse_a"),
+    ("griffin_spmm", "griffin_spmm"), ("griffin_spmm_dual", "griffin_spmm")])
+def test_kernel_carries_its_name(one_chip, kernel, name):
+    """Each kernel's custom call is the HLO instruction ``%<name>.N``: the
+    name its op events carry in a chip profile."""
+    fn, shapes = _kernel_call(kernel, 8, *SHAPES[0], 8, one_chip)
+    calls = [ln for ln in _compile(fn, *shapes).as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert calls and all(f"%{name}." in ln for ln in calls), calls
+
+
 def test_full_width_decode_step_compiles_with_kernels(one_chip, stablelm):
     """stablelm-1.6b's decode step at published widths (24 layers, bf16,
     8 slots x 2048 cache) with every GEMM on the Pallas kernels: the
