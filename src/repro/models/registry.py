@@ -26,10 +26,13 @@ class ModelApi:
     init: Callable[[jax.Array], Params]
     loss: Callable[..., jax.Array]            # (params, batch) -> scalar
     prefill: Callable[..., Any]               # (params, batch) -> (cache, logits)
-    decode_step: Callable[..., Any]           # (params, cache, token) -> (logits, cache)
+    decode_step: Callable[..., Any]           # (params, cache, token, live=None) -> (logits, cache)
     init_cache: Callable[[int, int], Params]  # (batch, length) -> cache
     param_count: Callable[[], int]            # analytic, excludes embeddings
     param_count_total: Callable[[], int]
+    # (cache, live) -> (2,) int32: per layer, the KV blocks the next decode
+    # step's attention reads and the blocks its arena holds (None: no count)
+    kv_blocks: Optional[Callable[..., jax.Array]] = None
 
 
 def _transformer_api(cfg: ModelConfig) -> ModelApi:
@@ -52,6 +55,7 @@ def _transformer_api(cfg: ModelConfig) -> ModelApi:
         init_cache=functools.partial(transformer.init_cache, cfg),
         param_count=lambda: _tf_param_count(cfg, active=True),
         param_count_total=lambda: _tf_param_count(cfg, active=False),
+        kv_blocks=functools.partial(transformer.kv_blocks, cfg),
     )
 
 

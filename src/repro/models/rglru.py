@@ -373,7 +373,9 @@ def prefill(cfg: ModelConfig, params: Params, tokens: jax.Array,
 
 
 def decode_step(cfg: ModelConfig, params: Params, cache: Params,
-                token: jax.Array):
+                token: jax.Array, live=None):
+    """One decode step.  ``live`` (the rows whose logits are used) is not
+    needed here."""
     x = params["embed"][token]
     pos = cache["pos"] + 1
     # "pages" marks a paged attention cache (runtime/paging.py): k/v become

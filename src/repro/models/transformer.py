@@ -23,10 +23,12 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
+from ..kernels.decode_attention import ops as live_kv
 from .attention import attention, decode_attention
-from .common import (act_fn, dense_init, griffin_linear, layer_scan,
-                     length_mask, paged_view, paged_write, remat_fn,
-                     rms_norm, rope, stack_layers, take_last, write_kv_layer)
+from .common import (act_fn, dense_init, execution_context, griffin_linear,
+                     layer_scan, length_mask, paged_view, paged_write,
+                     remat_fn, rms_norm, rope, stack_layers, take_last,
+                     write_kv_layer)
 from .moe import init_moe, moe_ffn
 
 Params = Dict[str, Any]
@@ -131,7 +133,7 @@ def block_train(cfg: ModelConfig, p: Params, x: jax.Array,
 
 
 def block_decode(cfg: ModelConfig, p: Params, x: jax.Array, k_all, v_all,
-                 layer, pos, cache_len: int):
+                 layer, pos, cache_len: int, plan=None):
     """One-token block of layer ``layer`` against the layer-stacked
     (L, B, S_cache, KVH, hd) caches; writes this layer's new K/V into them
     in place (``write_kv_layer``) and returns them updated.  Sliding-window
@@ -141,27 +143,89 @@ def block_decode(cfg: ModelConfig, p: Params, x: jax.Array, k_all, v_all,
     of per-row positions (continuous-batching slot pools,
     runtime/engine.py): each row ropes, writes and masks at its own
     position; with equal entries the vector path is bit-identical to the
-    scalar one (every op below is row-wise)."""
+    scalar one (every op below is row-wise).
+
+    ``plan`` (the kernel's ``step_plan``, which ``decode_step`` builds
+    once per step where ``runs_live_kv`` holds) runs attention in the
+    live-KV kernel (``kernels/decode_attention``), which
+    writes the new K/V and reads each row's valid positions only; a row
+    the plan marks dead writes and reads nothing and attends to zeros.
+    Without it ``decode_attention`` reads the layer's whole cache."""
     h = rms_norm(x, p["ln1"], cfg.norm_eps)
     per_slot = pos.ndim > 0
     q, k, v = _qkv(cfg, p, h,
                    positions=pos[:, None] if per_slot else pos[None])
-    rolling = cfg.window is not None and cache_len <= cfg.window
     with jax.named_scope("attention"):
-        slot = jnp.where(rolling, pos % cache_len,
-                         jnp.minimum(pos, cache_len - 1))
-        k_all = write_kv_layer(k_all, layer, k, slot)
-        v_all = write_kv_layer(v_all, layer, v, slot)
-        # valid length: rolling caches become fully valid once wrapped
-        eff_pos = jnp.where(rolling, jnp.minimum(pos, cache_len - 1), pos)
-        win = None if rolling else cfg.window
-        o = decode_attention(q, k_all[layer], v_all[layer], eff_pos,
-                             window=win)
+        if plan is not None:
+            o, k_all, v_all = live_kv.live_kv_attention(
+                q, k, v, k_all, v_all, layer, plan,
+                interpret=execution_context().interpret)
+        else:
+            slot, eff_pos, win = _cache_index(cfg, pos, cache_len)
+            k_all = write_kv_layer(k_all, layer, k, slot)
+            v_all = write_kv_layer(v_all, layer, v, slot)
+            o = decode_attention(q, k_all[layer], v_all[layer], eff_pos,
+                                 window=win)
     B = x.shape[0]
     x = x + griffin_linear(o.reshape(B, 1, -1), p["wo"]).astype(x.dtype)
     h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
     f, _ = _ffn(cfg, p, h2, decode=True)
     return (x + f).astype(x.dtype), k_all, v_all
+
+
+def _cache_index(cfg: ModelConfig, pos, cache_len: int):
+    """(slot, eff_pos, window) of a decode step at ``pos`` on a fixed
+    cache of ``cache_len`` positions: where the new K/V goes, the last
+    valid position and the window left to mask.  Sliding-window archs
+    whose cache fits the window roll it, and a rolling cache becomes fully
+    valid once wrapped."""
+    rolling = cfg.window is not None and cache_len <= cfg.window
+    slot = jnp.where(rolling, pos % cache_len,
+                     jnp.minimum(pos, cache_len - 1))
+    eff_pos = jnp.where(rolling, jnp.minimum(pos, cache_len - 1), pos)
+    return slot, eff_pos, None if rolling else cfg.window
+
+
+def runs_live_kv(cfg: ModelConfig, cache_len: int, use_kernels: bool,
+                 mesh) -> bool:
+    """Whether ``block_decode`` serves attention over a fixed arena of
+    ``cache_len`` positions with the live-KV kernel: Pallas kernels on one
+    device (``use_kernels`` and no SPMD ``mesh``), no window narrower than
+    the arena (a rolling cache has none), an arena of whole KV blocks
+    (``BLOCK_S`` positions each), and one the device keeps position-minor
+    (``position_minor``: a head size that is not a multiple of 128)."""
+    no_window = cfg.window is None or cache_len <= cfg.window
+    return (no_window and use_kernels and mesh is None
+            and cache_len % live_kv.BLOCK_S == 0
+            and live_kv.position_minor(cfg.hd))
+
+
+def _live_kv_lengths(cfg: ModelConfig, cache: Params, live):
+    """(lengths, slots) of the next decode step on ``cache`` where it runs
+    the live-KV kernel (``runs_live_kv`` in the current execution scope),
+    else None.  lengths: (B,) valid positions per row after the write,
+    capped at the cache (a dead row's position keeps advancing), 0 for a
+    row that ``live`` (optional (B,) bool) marks dead."""
+    if "pages" in cache:
+        return None
+    B, S = cache["k"].shape[1:3]
+    ctx = execution_context()
+    if not runs_live_kv(cfg, S, ctx.use_kernels, ctx.spmd_mesh):
+        return None
+    slot, eff_pos, _ = _cache_index(cfg, cache["pos"] + 1, S)
+    n = jnp.broadcast_to(jnp.minimum(eff_pos + 1, S), (B,))
+    return (n if live is None else jnp.where(live, n, 0)), slot
+
+
+def kv_blocks(cfg: ModelConfig, cache: Params, live=None) -> jax.Array:
+    """(2,) int32: per layer, the KV blocks the next decode step's live-KV
+    attention reads on ``cache`` and the blocks its arena holds; zeros
+    where the step keeps ``decode_attention``.  ``live`` as for
+    ``decode_step``."""
+    rows = _live_kv_lengths(cfg, cache, live)
+    if rows is None:
+        return jnp.zeros((2,), jnp.int32)
+    return live_kv.kv_blocks(rows[0], cache["k"].shape[2])
 
 
 def block_decode_paged(cfg: ModelConfig, p: Params, x: jax.Array, k_pool,
@@ -270,22 +334,26 @@ def prefill(cfg: ModelConfig, params: Params, tokens: jax.Array,
 
 
 def decode_step(cfg: ModelConfig, params: Params, cache: Params,
-                token: jax.Array) -> Tuple[jax.Array, Params]:
+                token: jax.Array, live=None) -> Tuple[jax.Array, Params]:
     """One decode step for the whole batch.  token: (B, 1) int32.
 
     A ``"pages"`` key marks a paged cache (runtime/paging.py): ``k``/``v``
     are then (L, num_pages, page_size, KVH, hd) pools indexed through the
     per-slot page table, with optional ``k_scale``/``v_scale`` leaves for
-    int8 pools."""
+    int8 pools.  ``live``: optional (B,) bool of the rows whose logits are
+    used; where the step runs the live-KV kernel (``runs_live_kv``) the
+    other rows' attention reads nothing and their logits are garbage."""
     x = params["embed"][token]
     pos = cache["pos"] + 1
     if "pages" in cache:
         return _decode_step_paged(cfg, params, cache, x, pos)
     clen = cache["k"].shape[2]
+    lengths = _live_kv_lengths(cfg, cache, live)
+    plan = None if lengths is None else live_kv.step_plan(*lengths)
 
     def body(carry, lp):
         x, ks, vs, i = carry
-        x, ks, vs = block_decode(cfg, lp, x, ks, vs, i, pos, clen)
+        x, ks, vs = block_decode(cfg, lp, x, ks, vs, i, pos, clen, plan)
         return (x, ks, vs, i + 1), None
 
     (x, ks, vs, _), _ = layer_scan(

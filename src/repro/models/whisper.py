@@ -187,9 +187,10 @@ def prefill(cfg: ModelConfig, params: Params, tokens: jax.Array,
 
 
 def decode_step(cfg: ModelConfig, params: Params, cache: Params,
-                token: jax.Array):
+                token: jax.Array, live=None):
     """``cache["pos"]`` is a scalar (lockstep batch) or a (B,) vector of
-    per-row positions (continuous-batching slot pools, runtime/engine.py)."""
+    per-row positions (continuous-batching slot pools, runtime/engine.py).
+    ``live`` (the rows whose logits are used) is not needed here."""
     x = params["embed"][token]
     pos = cache["pos"] + 1
     per_slot = pos.ndim > 0

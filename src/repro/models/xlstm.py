@@ -389,11 +389,12 @@ def prefill(cfg: ModelConfig, params: Params, tokens: jax.Array,
 
 
 def decode_step(cfg: ModelConfig, params: Params, cache: Params,
-                token: jax.Array):
+                token: jax.Array, live=None):
     """One recurrent step.  The mLSTM/sLSTM state math is position-free, so
     per-slot serving (continuous batching, runtime/engine.py) needs no
     vector-position branch here: ``cache["pos"]`` increments elementwise
-    whether it is the lockstep scalar or a (B,) per-slot vector."""
+    whether it is the lockstep scalar or a (B,) per-slot vector.  ``live``
+    (the rows whose logits are used) is not needed here."""
     x = params["embed"][token]
     x, new_cache = _scan_groups_with_state(cfg, params, cache, x, chunk=1)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)

@@ -706,11 +706,17 @@ class ServeEngine:
         # live_rows: live row-steps of the decode chunks (the chunk's
         # on-device live count, so emitted == prefill_calls + live_rows);
         # prefill_tokens / prefill_padded_tokens: prompt tokens prefilled
-        # and the bucket lengths they ran at
+        # and the bucket lengths they ran at; kv_blocks_read /
+        # kv_blocks_arena: per layer, the KV blocks the chunks' live-KV
+        # attention kernel reads and the blocks the arena holds over the
+        # same steps (the chunk's on-device ``ModelApi.kv_blocks`` sums,
+        # fetched in the tick's one sync; both 0 where the kernel does not
+        # run)
         self.stats = {"decode_steps": 0, "prefill_calls": 0, "emitted": 0,
                       "retraces": 0, "chunk_calls": 0, "host_syncs": 0,
                       "live_rows": 0, "prefill_tokens": 0,
-                      "prefill_padded_tokens": 0}
+                      "prefill_padded_tokens": 0, "kv_blocks_read": 0,
+                      "kv_blocks_arena": 0}
         self._t_added: Dict[int, float] = {}    # rid -> wall stamp of add
         self.prefill_buckets: set = set()       # distinct admitted shapes
         # prompt buckets longer than the usable cache window cannot be
@@ -1121,17 +1127,20 @@ class ServeEngine:
                 chunk_fn = self._fns()[2](chunk)
                 with self._scope():
                     (self.cache, self._tokens, self._remaining, ring,
-                     zf_num, zf_den) = chunk_fn(self.params, self.cache,
-                                                self._tokens, self._remaining)
+                     zf_num, zf_den, kv) = chunk_fn(self.params, self.cache,
+                                                    self._tokens,
+                                                    self._remaining)
                 self._poll_fault("decode")
             with span("sync"):
-                ring, first_toks, zf_num, zf_den = jax.device_get(
-                    (ring, [t for _, _, t in pending], zf_num, zf_den))
+                ring, first_toks, zf_num, zf_den, kv = jax.device_get(
+                    (ring, [t for _, _, t in pending], zf_num, zf_den, kv))
             self.stats["host_syncs"] += 1
             with span("emit") as sp:
                 self.stats["chunk_calls"] += 1
                 self.stats["decode_steps"] += chunk
                 self.stats["live_rows"] += int(zf_den)
+                self.stats["kv_blocks_read"] += int(kv[0])
+                self.stats["kv_blocks_arena"] += int(kv[1])
                 # prefill-boundary emissions first: the chunk consumed these
                 # tokens as its first feedback, so they precede the ring rows
                 for (slot, rid, _), tok in zip(pending, first_toks):
@@ -1146,7 +1155,9 @@ class ServeEngine:
                 self._since_measure += chunk
                 if zf_den > 0 and self._since_measure >= self.measure_every:
                     self._measure(float(zf_num) / float(zf_den))
-                sp.set_metadata(tokens=len(self.events) - ev_start)
+                sp.set_metadata(tokens=len(self.events) - ev_start,
+                                kv_blocks_read=int(kv[0]),
+                                kv_blocks_arena=int(kv[1]))
         else:
             self.clock += 1
         return self.events[ev_start:]

@@ -148,7 +148,8 @@ def mesh_serve_fns(api: ModelApi, mesh: Mesh, params: Any, num_slots: int,
         api, decode_chunk,
         lambda fn: jax.jit(fn,
                            in_shardings=(p_sh, c_sh, rep, rep),
-                           out_shardings=(c_sh, rep, rep, rep, rep, rep),
+                           out_shardings=(c_sh, rep, rep, rep, rep, rep,
+                                          rep),
                            donate_argnums=(1, 2, 3)))
     return prefill_jit, decode_jit, chunk_for, (p_sh, c_sh, rep)
 

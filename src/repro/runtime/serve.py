@@ -77,29 +77,37 @@ def make_decode_chunk_fn(api: ModelApi, decode_chunk: int) -> Callable:
     is ``remaining > 0``; live rows contribute their exact-zero logit
     fraction to a running (num, den) pair — the engine's workload-category
     measurement — and decrement ``remaining``.  Returns the small arrays
-    the host actually needs: the (chunk, B) token ring plus the two
-    measurement scalars.  Finished and never-admitted rows keep decoding
+    the host actually needs: the (chunk, B) token ring, the two
+    measurement scalars and the (2,) KV-block counts of the chunk's
+    attention (``ModelApi.kv_blocks``: blocks read and blocks held, per
+    layer, summed over the steps; zeros where the model gives none).  Finished and never-admitted rows keep decoding
     garbage (row-wise independence makes that harmless — DESIGN.md
     Section 8); they are excluded from both the ring drain (host side) and
-    the measurement (the live mask here).
+    the measurement (the live mask here).  The decode step gets the live
+    mask too (``decode_step(..., live=)``), so attention can skip the dead
+    rows' KV.
     """
 
     def chunk_fn(params, cache, tokens, remaining):
         def body(carry, _):
             cache, tokens, remaining = carry
-            logits, cache = api.decode_step(params, cache, tokens)
-            toks = jnp.argmax(logits, -1).astype(jnp.int32)       # (B,)
             live = remaining > 0
+            kv = (jnp.zeros((2,), jnp.int32) if api.kv_blocks is None
+                  else api.kv_blocks(cache, live))
+            logits, cache = api.decode_step(params, cache, tokens, live=live)
+            toks = jnp.argmax(logits, -1).astype(jnp.int32)       # (B,)
             zf_rows = jnp.mean((logits == 0).astype(jnp.float32), axis=-1)
             zf_num = jnp.sum(zf_rows * live)
             zf_den = jnp.sum(live.astype(jnp.float32))
             remaining = remaining - live.astype(remaining.dtype)
-            return (cache, toks[:, None], remaining), (toks, zf_num, zf_den)
+            return ((cache, toks[:, None], remaining),
+                    (toks, zf_num, zf_den, kv))
 
-        carry, (ring, nums, dens) = jax.lax.scan(
+        carry, (ring, nums, dens, kvs) = jax.lax.scan(
             body, (cache, tokens, remaining), length=decode_chunk)
         cache, tokens, remaining = carry
-        return cache, tokens, remaining, ring, nums.sum(), dens.sum()
+        return (cache, tokens, remaining, ring, nums.sum(), dens.sum(),
+                kvs.sum(0))
 
     return chunk_fn
 
@@ -160,7 +168,8 @@ def jit_serve_fns(api: ModelApi, mesh: Mesh, batch: int, cache_len: int,
         api, decode_chunk,
         lambda fn: jax.jit(fn,
                            in_shardings=(p_sh, c_sh, rep, rep),
-                           out_shardings=(c_sh, rep, rep, rep, rep, rep),
+                           out_shardings=(c_sh, rep, rep, rep, rep, rep,
+                                          rep),
                            donate_argnums=(1, 2, 3)))
     return prefill_jit, decode_jit, chunk_for, (p_sh, c_sh, logits_sh)
 

@@ -73,7 +73,7 @@ def fake_api(vocab: int = 17, zero_logits: bool = False) -> ModelApi:
                  "pos": jnp.asarray(toks.shape[1] - 1, jnp.int32)}
         return cache, logits_of(state)
 
-    def decode_step(params, cache, token):
+    def decode_step(params, cache, token, live=None):
         state = (cache["state"] + token) % vocab
         return logits_of(state), {"state": state, "pos": cache["pos"] + 1}
 
@@ -126,12 +126,13 @@ def test_jit_serve_fns_run_on_one_device_mesh():
     cache3, logits3 = prefill_jit(params, {"tokens": toks})
     tokens = jnp.argmax(logits3, -1).astype(jnp.int32)[:, None]
     remaining = jnp.asarray([3, 0], jnp.int32)
-    cache3, tokens, remaining, ring, zn, zd = chunk_for(3)(
+    cache3, tokens, remaining, ring, zn, zd, kv = chunk_for(3)(
         params, cache3, tokens, remaining)
     assert ring.shape == (3, B) and ring.dtype == jnp.int32
     assert int(cache3["pos"]) == S + 2
     assert list(np.asarray(remaining)) == [0, 0]
     assert float(zd) == 3.0                     # one live row x three steps
+    assert list(np.asarray(kv)) == [0, 0]       # no live-KV kernel here
     assert chunk_for(3) is chunk_for(3)         # ladder memoized per length
 
 
@@ -279,17 +280,27 @@ def test_chunk_fn_masks_dead_rows_out_of_measurement():
     ``logits[jnp.asarray(active)]`` gather guarded against."""
     api = fake_api(zero_logits=True)      # one-hot logits: zf ~ 16/17
     params = api.init(jax.random.PRNGKey(0))
+    seen = []                              # live masks the steps are given
+
+    def decode_step(params, cache, token, live=None):
+        seen.append(live)
+        return fake_api(zero_logits=True).decode_step(params, cache, token)
+
+    api = dataclasses.replace(api, decode_step=decode_step)
     chunk_fn = make_decode_chunk_fn(api, 4)
     cache = {"state": jnp.asarray([[3], [9]], jnp.int32),
              "pos": jnp.zeros((2,), jnp.int32)}
     tokens = jnp.asarray([[1], [2]], jnp.int32)
     # row 1 is dead: its one-hot rows would dominate the mean if leaked
-    _, _, _, _, zn, zd = chunk_fn(params, cache, tokens,
+    _, _, _, _, zn, zd, _ = chunk_fn(params, cache, tokens,
                                   jnp.asarray([4, 0], jnp.int32))
     assert float(zd) == 4.0               # only row 0, all four steps
     assert 0.9 < float(zn) / float(zd) < 1.0
+    # the decode step is told which rows are live (traced once, in scan)
+    assert len(seen) == 1 and seen[0].shape == (2,)
+    assert seen[0].dtype == jnp.bool_
     # all-dead pool: denominator 0, numerator 0 (engine skips measuring)
-    _, _, _, _, zn0, zd0 = chunk_fn(params, cache, tokens,
+    _, _, _, _, zn0, zd0, _ = chunk_fn(params, cache, tokens,
                                     jnp.asarray([0, 0], jnp.int32))
     assert float(zd0) == 0.0 and float(zn0) == 0.0
 
@@ -318,7 +329,7 @@ def test_engine_measurement_ignores_stale_and_unadmitted_slots():
              "pos": jnp.asarray(batch["tokens"].shape[1] - 1, jnp.int32)},
             logits_of_mixed(jnp.sum(batch["tokens"], -1, keepdims=True
                                     ).astype(jnp.int32) % vocab)),
-        decode_step=lambda params, cache, token: (
+        decode_step=lambda params, cache, token, live=None: (
             logits_of_mixed((cache["state"] + token) % vocab),
             {"state": (cache["state"] + token) % vocab,
              "pos": cache["pos"] + 1}))
@@ -696,3 +707,115 @@ def test_layer_scopes_name_the_compiled_chunk_ops():
     names = re.findall(r'op_name="([^"]*)"', text)
     for scope in ("attention", "griffin_linear"):
         assert any(f"/{scope}/" in n for n in names), scope
+
+
+# ---------------------------------------------------------------------------
+# live-KV decode attention (kernels/decode_attention) in the served path
+# ---------------------------------------------------------------------------
+
+def _kernel_config(num_slots=3, cache_len=256, decode_chunk=4):
+    from repro.runtime.config import ArenaConfig, EngineConfig
+    return EngineConfig(arena=ArenaConfig(num_slots=num_slots,
+                                          cache_len=cache_len)
+                        ).with_fields(decode_chunk=decode_chunk,
+                                      use_kernels=True, interpret=True)
+
+
+def test_engine_live_kv_kernel_keeps_every_live_token(monkeypatch):
+    """The fused engine with kernels on a small transformer: the same
+    tokens for every request with the live-KV attention kernel and with
+    ``decode_attention`` in its place."""
+    from repro.models import transformer
+    api = build_model(get_config("stablelm-1.6b").reduced())
+    params = api.init(jax.random.PRNGKey(0))
+
+    def trace():
+        return synthetic_trace(api.cfg, num_requests=5, seed=3,
+                               prompt_lens=(5, 11), gen_lens=(3, 9),
+                               arrival_every=1)
+
+    eng = ServeEngine(api, params, config=_kernel_config())
+    outs = eng.run(trace())
+    assert eng.stats["kv_blocks_read"] > 0
+    monkeypatch.setattr(transformer, "runs_live_kv", lambda *a, **k: False)
+    plain = ServeEngine(api, params, config=_kernel_config())
+    want = plain.run(trace())
+    assert plain.stats["kv_blocks_read"] == plain.stats["kv_blocks_arena"] \
+        == 0
+    assert {r: o.tokens for r, o in outs.items()} == \
+        {r: o.tokens for r, o in want.items()}
+
+
+def test_engine_counts_kv_blocks_by_hand(tmp_path):
+    """``kv_blocks_read`` / ``kv_blocks_arena`` on a fixed trace: 256-token
+    blocks of a 512-token arena; request 0 decodes at lengths 255..258
+    (1, 1, 2, 2 blocks), request 1 at 11 and 12 (1, 1).  The emit spans
+    carry the same counts."""
+    api = build_model(get_config("stablelm-1.6b").reduced())
+    eng = ServeEngine(api, api.init(jax.random.PRNGKey(0)),
+                      config=_kernel_config(num_slots=2, cache_len=512))
+    reqs = [Request(0, np.full((254,), 3, np.int32), 5, arrival=0),
+            Request(1, np.full((10,), 4, np.int32), 3, arrival=0)]
+    with jax.profiler.trace(str(tmp_path)):
+        _ticks(eng, reqs)
+    st = eng.stats
+    assert st["kv_blocks_read"] == (1 + 1 + 2 + 2) + (1 + 1)
+    assert st["kv_blocks_arena"] == st["decode_steps"] * 2 * 2
+    emits = [a for n, _, _, a in _engine_spans(str(tmp_path))
+             if n == "engine.emit"]
+    assert emits
+    for key in ("kv_blocks_read", "kv_blocks_arena"):
+        assert sum(a[key] for a in emits) == st[key]
+
+
+def _decode_jaxprs(path):
+    """Jaxprs of one decode step on ``path`` with a live mask and without
+    one (same arguments)."""
+    from jax.sharding import Mesh
+    from repro.runtime.engine import _promote_arena
+    from repro.runtime.paging import build_spec, paged_tree
+    arch = "mixtral-8x7b" if path == "window" else "stablelm-1.6b"
+    cfg = get_config(arch).reduced()
+    api = build_model(cfg)
+    B, S = 2, 200 if path == "part_block" else 256
+    scope = dict(use_kernels=True, interpret=True)
+    if path == "window":         # a 256-position cache past the 32 window
+        cache = dataclasses.replace(cfg, window=None)
+        cache = _promote_arena(build_model(cache).init_cache(B, S), B)
+    else:
+        cache = _promote_arena(api.init_cache(B, S), B)
+    if path == "paged":
+        spec, _ = build_spec(api, B, S, 8, None, "fp32")
+        cache = paged_tree(cache, B, spec)
+    if path == "kernels_off":
+        scope = dict(use_kernels=False)
+    if path == "mesh":
+        scope["spmd_mesh"] = Mesh(np.array(jax.devices()[:1]).reshape(1, 1),
+                                  ("data", "model"))
+    params = api.init(jax.random.PRNGKey(0))
+    args = (params, cache, jnp.ones((B, 1), jnp.int32),
+            jnp.asarray([True, False]))
+    with sparse_execution(**scope):
+        with_live = jax.make_jaxpr(
+            lambda p, c, t, l: api.decode_step(p, c, t, live=l))(*args)
+        without = jax.make_jaxpr(
+            lambda p, c, t, l: api.decode_step(p, c, t))(*args)
+    return str(with_live), str(without)
+
+
+@pytest.mark.parametrize("path", ["kernels_off", "mesh", "paged", "window",
+                                  "part_block", "fixed_arena"])
+def test_decode_paths_keep_plain_attention(path):
+    """Only the single-device kernel path over a fixed arena of whole
+    256-position KV blocks runs the live-KV kernel (and reads ``live``);
+    the paged arena, a mesh scope, a window narrower than the cache, an
+    arena whose length is not a block multiple (the serving CLI's derived
+    lengths) and kernels off keep ``decode_attention`` and ignore the
+    mask."""
+    with_live, without = _decode_jaxprs(path)
+    kernel = "name=decode_attention"
+    if path == "fixed_arena":
+        assert kernel in with_live and with_live != without
+    else:
+        assert kernel not in with_live
+        assert with_live == without
