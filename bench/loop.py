@@ -5,7 +5,8 @@ builds the model and the engine through the program's own serving entry
 points (``build_model``, ``sparsify_params``, ``launch.serve``'s parser and
 ``build_engine``), feeds the engine on the wall clock, and stamps what
 comes back.  The weights come from ``bench.weights``; the check runs
-``bench.reference``, which imports nothing of the program.
+``bench.reference`` with the configuration's block (``bench.blocks``),
+which import nothing of the program.
 """
 from __future__ import annotations
 
@@ -17,7 +18,7 @@ from typing import Dict, List, Optional
 import jax
 import numpy as np
 
-from . import reference, traffic, weights
+from . import blocks, reference, traffic, weights
 
 # a request in flight when the window closes gets this long to finish
 DRAIN_S = 30.0
@@ -26,16 +27,20 @@ WARM_RID = 10 ** 6
 
 
 def program_config(conf: dict):
-    """The program's model config, at the sizes the configuration file
-    states."""
+    """The program's model config with every ``arch`` key of the
+    configuration file in the field of the same name (a JSON list as a
+    tuple).  A key the program's config has no field for is refused, so
+    no size or mechanism the file states is served at a default."""
     from repro.configs import get_config
-    a = conf["arch"]
-    return dataclasses.replace(
-        get_config(conf["program"]), num_layers=a["num_layers"],
-        d_model=a["d_model"], num_heads=a["num_heads"],
-        num_kv_heads=a["num_kv_heads"], head_dim=a["head_dim"],
-        d_ff=a["d_ff"], vocab_size=a["vocab_size"], norm_eps=a["norm_eps"],
-        rope_theta=a["rope_theta"], act=a["act"], dtype=a["dtype"])
+    base = get_config(conf["program"])
+    fields = {f.name for f in dataclasses.fields(base)}
+    unknown = sorted(set(conf["arch"]) - fields)
+    if unknown:
+        raise ValueError(f"configuration {conf['name']!r} sets {unknown}, "
+                         f"which the program's ModelConfig does not have")
+    return dataclasses.replace(base, **{
+        k: tuple(v) if isinstance(v, list) else v
+        for k, v in conf["arch"].items()})
 
 
 def engine_argv(conf: dict) -> List[str]:
@@ -293,6 +298,7 @@ def check(conf: dict, mix: dict, seed: int, reqs: Dict[int, dict],
     shapes, shardings = param_layout(conf, api)
     w = weights.make(shapes, conf, seed, shardings)
     rids = sample(reqs, seed, ck["min_tokens"])
+    block = blocks.name_of(conf)
     seq_len = conf["deployment"]["cache_len"]
     n_max = mix["output_tokens"]["max"]
     out = {"requests": len(rids), "tokens": 0, "served_gap": 0.0}
@@ -300,12 +306,12 @@ def check(conf: dict, mix: dict, seed: int, reqs: Dict[int, dict],
         out["control_gap"] = 0.0
     for rid in rids:
         r = reqs[rid]
-        g = reference.served_gaps(w, conf["arch"], r["prompt"], r["tokens"],
-                                  seq_len, n_max)
+        g = reference.served_gaps(w, block, conf["arch"], r["prompt"],
+                                  r["tokens"], seq_len, n_max)
         out["tokens"] += len(g)
         out["served_gap"] = max(out["served_gap"], float(g.max()))
         if control:
-            c = reference.served_gaps(w, conf["arch"], r["prompt"],
+            c = reference.served_gaps(w, block, conf["arch"], r["prompt"],
                                       r["tokens"], seq_len, n_max,
                                       control=True)
             out["control_gap"] = max(out["control_gap"], float(c.max()))

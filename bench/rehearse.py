@@ -4,7 +4,7 @@
 
 1. The open loop, warm-up and check of each configuration at a tiny size
    (two layers, narrow widths, float32), with the Pallas kernels in
-   interpret mode: every configuration runs through its own path, the
+   interpret mode: every configuration runs through its own path, a
    four-chip one on four virtual CPU devices.
 2. ``--compile``: the decode chunk and the longest prefill bucket of every
    configuration at full size, compiled for a described ``v5e:2x2`` (one

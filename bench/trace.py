@@ -24,9 +24,6 @@ import re
 from collections import defaultdict
 from typing import Dict, List
 
-# Pallas kernels by the name they carry in the trace, where they carry one
-KERNELS = {"_spmm_kernel": "griffin_spmm", "_matmul_kernel": "dense_gemm",
-           "_sparse_a_kernel": "sparse_a"}
 COLLECTIVE = re.compile(r"^(all-gather|all-reduce|collective-permute|"
                         r"reduce-scatter|all-to-all)")
 # a TPU op event is named by its HLO instruction: "%name.N = shape op(...)"
@@ -58,16 +55,14 @@ def load(path: str) -> List[dict]:
                 for e in line.events:
                     out.append({"plane": plane.name, "kind": kind,
                                 "name": e.name, "start_ns": e.start_ns,
-                                "dur_ns": e.duration_ns,
-                                "text": " ".join(str(v) for _, v in e.stats
-                                                 if isinstance(v, str))})
+                                "dur_ns": e.duration_ns})
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 for e in line.events:
                     if e.name in HOST_SPANS:
                         out.append({"plane": "host", "kind": "host",
                                     "name": e.name, "start_ns": e.start_ns,
-                                    "dur_ns": e.duration_ns, "text": ""})
+                                    "dur_ns": e.duration_ns})
     return out
 
 
@@ -82,22 +77,15 @@ def opcode(name: str) -> str:
 
 
 def kernel_of(ev: dict):
-    """The Pallas kernel an op event runs, or None.  Without a kernel name
-    in the event, a ``tpu_custom_call`` is told by its operands: the
-    Sparse.B kernel takes two int32 scalar-prefetch operands (block ids
-    and counts) before its bf16 operands, the dense GEMM kernel none.
-    (The Sparse.A kernel also prefetches two, and is counted as
-    griffin_spmm; it runs only under an A-sparse Mode, which no cell
-    selects.)"""
-    hay = ev["name"] + " " + ev.get("text", "")
-    for pat, name in KERNELS.items():
-        if pat in hay:
-            return name
-    if 'custom_call_target="tpu_custom_call"' not in ev["name"]:
+    """The Pallas kernel an op event runs, or None.  A ``tpu_custom_call``
+    is booked under the name of its HLO instruction, which is the
+    ``pallas_call``'s own (``%griffin_spmm.3`` -> ``griffin_spmm``,
+    ``%decode_attention.7``, and whatever name a later kernel gives
+    itself)."""
+    name = ev["name"]
+    if 'custom_call_target="tpu_custom_call"' not in name:
         return None
-    args = ev["name"].split("custom-call(", 1)[-1]
-    lead = re.findall(r"(?:^|, )(s32|bf16|f32|u32|s8)\[", args)[:2]
-    return "griffin_spmm" if lead == ["s32", "s32"] else "dense_gemm"
+    return re.sub(r"\.\d+$", "", INSTR.match(name).group(1))
 
 
 def _merge(intervals):

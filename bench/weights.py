@@ -16,6 +16,10 @@ Which blocks survive is drawn from the configuration's ``mask_seed``, the
 values from the run's seed.  So every seed serves the same block pattern,
 with the uneven column tiles a pruner leaves, and the compacted shapes, and
 every compiled program, are the same for every seed.
+
+Which rule a leaf gets is its kind in the configuration's block
+(``bench.blocks``, its ``LEAVES``): a leaf the block does not list has no
+rule and is refused.
 """
 from __future__ import annotations
 
@@ -24,8 +28,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-NORMS = ("ln1", "ln2", "final_norm", "qn", "kn")
-GEMMS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down", "head")
+from . import blocks
 
 
 def leaf_name(path) -> str:
@@ -79,17 +82,21 @@ def _pruning(name: str, conf: dict) -> Optional[dict]:
     return None
 
 
-def _leaf(key, mkey, name: str, sd, conf: dict):
+def _leaf(key, mkey, name: str, kind, sd, conf: dict):
+    """The leaf ``name`` by the rule of its ``kind``: ``norm`` (a norm's
+    scale is 1 + w) and ``bias`` N(0, norm_std), ``embed`` N(0, embed_std),
+    ``gemm`` N(0, 1/fan-in), block-pruned where the file prunes it."""
     init = conf["init"]
-    if name in NORMS:
+    if kind in ("norm", "bias"):
         return (init["norm_std"] * jax.random.normal(key, sd.shape)
                 ).astype(sd.dtype)
-    if name == "embed":
+    if kind == "embed":
         return (init["embed_std"] * jax.random.normal(key, sd.shape)
                 ).astype(sd.dtype)
-    if name in GEMMS:
+    if kind == "gemm":
         return _gemm(key, mkey, sd.shape, sd.dtype, _pruning(name, conf))
-    raise ValueError(f"no rule for weight leaf {name!r}")
+    raise ValueError(f"no rule for weight leaf {name!r} (kind {kind!r}) "
+                     f"in block {blocks.name_of(conf)!r}")
 
 
 def seed_words(seed: int):
@@ -108,13 +115,14 @@ def make(shapes, conf: dict, seed: int, shardings=None):
     """Weights of the tree ``shapes`` (ShapeDtypeStructs) from ``seed``,
     placed by ``shardings`` (default: the default device)."""
     flat, treedef = jax.tree_util.tree_flatten_with_path(shapes)
+    kinds = blocks.of(conf).LEAVES
 
     def gen(lo, hi):
         key = jax.random.fold_in(jax.random.key(0), lo)
         key = jax.random.fold_in(key, hi)
         return jax.tree_util.tree_unflatten(treedef, [
             _leaf(jax.random.fold_in(key, i), _mask_key(conf, i),
-                  leaf_name(path), sd, conf)
+                  leaf_name(path), kinds.get(leaf_name(path)), sd, conf)
             for i, (path, sd) in enumerate(flat)])
 
     return jax.jit(gen, out_shardings=shardings)(*seed_words(seed))
@@ -124,10 +132,11 @@ def masks(shapes, conf: dict) -> dict:
     """{leaf name: (..., nbk, nbn) bool} of every pruned leaf of ``shapes``:
     the block pattern :func:`make` gives every seed."""
     flat, _ = jax.tree_util.tree_flatten_with_path(shapes)
+    kinds = blocks.of(conf).LEAVES
     out = {}
     for i, (path, sd) in enumerate(flat):
         name = leaf_name(path)
-        pruning = _pruning(name, conf) if name in GEMMS else None
+        pruning = _pruning(name, conf) if kinds.get(name) == "gemm" else None
         if pruning is not None:
             out[name] = block_mask(_mask_key(conf, i), sd.shape, pruning)
     return out

@@ -10,27 +10,24 @@ output axis:
             int32 count per output tile, / chips (pruned GEMMs only)
           + the activation rows, whole on every chip (rows * k)
           + the output rows of this chip's share (rows * n / chips)
-``rows`` is the decode batch, every slot of the arena.
+``rows`` is the decode batch, every slot of the arena.  Which GEMMs a step
+runs is the configuration's block's to say (``bench.blocks``).
 """
 from __future__ import annotations
 
 from typing import Dict, List
 
+from . import blocks
 from .weights import block_plan
 
 ITEM = 2                     # bfloat16 bytes
 META = 4                     # int32 metadata entry
 
 
-def gemm_shapes(arch: dict) -> List[tuple]:
-    """(name, k, n, count per step) of every weight GEMM of a decode step."""
-    d, h, kvh, hd = (arch["d_model"], arch["num_heads"],
-                     arch["num_kv_heads"], arch["head_dim"])
-    f, L = arch["d_ff"], arch["num_layers"]
-    return [("wq", d, h * hd, L), ("wk", d, kvh * hd, L),
-            ("wv", d, kvh * hd, L), ("wo", h * hd, d, L),
-            ("w_gate", d, f, L), ("w_up", d, f, L), ("w_down", f, d, L),
-            ("head", d, arch["vocab_size"], 1)]
+def gemm_shapes(conf: dict) -> List[tuple]:
+    """(name, k, n, count per step) of every weight GEMM of a decode step,
+    as the configuration's block lists them."""
+    return blocks.of(conf).gemm_shapes(conf["arch"])
 
 
 def gemm(name: str, k: int, n: int, rows: int, pruning: dict,
@@ -54,7 +51,7 @@ def decode_step(conf: dict) -> Dict[str, float]:
     d = conf["deployment"]
     chips, rows = d["chips"], d["slots"]
     tot = {"params": 0.0, "flops": 0.0, "bytes": 0.0}
-    for name, k, n, count in gemm_shapes(conf["arch"]):
+    for name, k, n, count in gemm_shapes(conf):
         g = gemm(name, k, n, rows, conf["pruning"], chips)
         for key in tot:
             tot[key] += count * g[key]
@@ -66,7 +63,7 @@ def lower_bound_s(conf: dict, peak: dict) -> float:
     GEMM bound by compute or by bandwidth, whichever is slower."""
     d = conf["deployment"]
     t = 0.0
-    for name, k, n, count in gemm_shapes(conf["arch"]):
+    for name, k, n, count in gemm_shapes(conf):
         g = gemm(name, k, n, d["slots"], conf["pruning"], d["chips"])
         t += count * max(g["flops"] / peak["bf16_flops"],
                          g["bytes"] / peak["hbm_bytes_per_s"])

@@ -3,7 +3,7 @@ the tiny rehearsal size on the CPU (the harness's look for a chip is the
 only part skipped): a sound run passes, the float8 control put in the
 program's place does not, and each fault a cell can have, planted under the
 timed path, makes ``correct`` come out false.  Every configuration file is
-driven, the four-chip one on four virtual devices."""
+driven, a four-chip one on four virtual devices."""
 import json
 import os
 import subprocess

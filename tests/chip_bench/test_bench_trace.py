@@ -1,7 +1,10 @@
-"""The benchmark's trace reduction, on events cut from a TPU v5e trace of
-the stablelm.b80.chat cell (``data/trace_events.json``: the traced slice,
-its host spans, three decode-chunk executions and 75 of their op events)
-and on small synthetic traces."""
+"""The benchmark's trace reduction, on events cut from TPU v5e traces of
+the benchmark's runs (``data/trace_events.json``: the stablelm.b80.chat
+slice cut to its first three decode chunks, their host spans, 81 op
+events of the first and the second's four buffer allocations;
+``data/trace_events_4chips.json``: one decode chunk
+on a 1x4 mesh) and on small synthetic traces."""
+import collections
 import json
 import sys
 from pathlib import Path
@@ -30,19 +33,54 @@ def test_opcodes_of_recorded_events():
 
 
 def test_kernels_told_by_their_operands():
-    spmm = [e for e in _ops() if trace.kernel_of(e) == "griffin_spmm"]
-    assert len(spmm) == 10
+    """The recorded kernels are told by their instruction's name, not by
+    their operands: decode_attention leads with one int32 operand and
+    bf16 ones, as no Sparse.B kernel does, and is still no dense_gemm."""
+    calls = [e for e in _ops() if trace.opcode(e["name"]) == "custom-call"]
+    told = collections.Counter(trace.kernel_of(e) for e in calls)
+    assert told == {"griffin_spmm": 10, "decode_attention": 6, None: 4}
     assert all('custom_call_target="tpu_custom_call"' in e["name"]
-               for e in spmm)
-    buffers = [e for e in _ops() if trace.opcode(e["name"]) == "custom-call"
-               and trace.kernel_of(e) is None]
-    assert buffers and all("AllocateBuffer" in e["name"] for e in buffers)
-    dense = {"name": '%_dense_matmul_jit.3 = bf16[8,4096]{1,0} custom-call('
-                     'bf16[8,4096]{1,0} %a, bf16[4096,4096]{1,0} %b), '
-                     'custom_call_target="tpu_custom_call"'}
-    assert trace.kernel_of(dense) == "dense_gemm"
-    assert trace.kernel_of({"name": "x", "text": "_spmm_kernel"}) == \
-        "griffin_spmm"
+               for e in calls if trace.kernel_of(e))
+    assert all("AllocateBuffer" in e["name"]
+               for e in calls if trace.kernel_of(e) is None)
+    # a kernel that gave itself no name is booked under the instruction's
+    # own name, never under another kernel's
+    unnamed = {"name": '%_run.3 = bf16[8,4096]{1,0} custom-call('
+                       'bf16[8,4096]{1,0} %a, bf16[4096,4096]{1,0} %b), '
+                       'custom_call_target="tpu_custom_call"'}
+    assert trace.kernel_of(unnamed) == "_run"
+
+
+# op events of kernels that name themselves, as the program's pallas_calls
+# do (``name=``); griffin_spmm and sparse_a lead with the same operands,
+# and fused_rmsnorm stands for a kernel a later PR adds
+NAMED = {
+    "decode_attention": '%decode_attention.7 = (bf16[10,32,64]{2,1,0}, '
+    'bf16[24,10,32,2048,64]{4,3,2,1,0}) custom-call(s32[96]{0} %plan, '
+    'bf16[10,32,64]{2,1,0} %q), custom_call_target="tpu_custom_call"',
+    "dense_gemm": '%dense_gemm.3 = bf16[8,256]{1,0} custom-call(bf16[8,4096]'
+    '{1,0} %x, bf16[4096,256]{1,0} %w), custom_call_target="tpu_custom_call"',
+    "sparse_a": '%sparse_a.5 = bf16[8,2048]{1,0} custom-call(s32[64]{0} %i, '
+    's32[16]{0} %c, bf16[8,2048]{1,0} %x, bf16[2048,2048]{1,0} %w), '
+    'custom_call_target="tpu_custom_call"',
+    "griffin_spmm": '%griffin_spmm.12 = bf16[10,2048]{1,0} custom-call('
+    's32[144]{0} %k, s32[16]{0} %c, bf16[10,2048]{1,0} %x, bf16[1152,2048]'
+    '{1,0} %w), custom_call_target="tpu_custom_call"',
+    "fused_rmsnorm": '%fused_rmsnorm.2 = f32[8,2048]{1,0} custom-call('
+    'f32[8,2048]{1,0} %x), custom_call_target="tpu_custom_call"',
+}
+
+
+@pytest.mark.parametrize("kernel", sorted(NAMED))
+def test_kernels_told_by_their_names(kernel):
+    ev = _ev("/device:TPU:0", "op", NAMED[kernel], 100, 200)
+    assert trace.kernel_of(ev) == kernel
+    red = trace.reduce([_ev("host", "host", "bench.slice", 0, 1000),
+                        _ev("/device:TPU:0", "module", "jit_chunk_fn(1)",
+                            50, 400), ev])
+    assert trace.kernel_sum(red, "chunk_fn", kernel) == pytest.approx(2e-7)
+    [(label, sec)] = red["top_ops"]
+    assert label == f"{kernel} @ jit_chunk_fn" and sec == pytest.approx(2e-7)
 
 
 def test_reduction_of_recorded_slice():
@@ -110,9 +148,9 @@ def test_two_devices_average_and_collectives():
 
 
 def test_reduction_of_recorded_four_chip_slice():
-    """Events of one decode chunk on each chip of a 1x4 mesh (minitron-8b,
-    TPU v5e): dense_gemm told by its operands, collectives summed, both
-    averaged over the four chips."""
+    """Events of one decode chunk on each chip of a 1x4 mesh (minitron-8b
+    widths, four layers, TPU v5e): dense_gemm told by its name under
+    ``shard_map``, collectives summed, both averaged over the four chips."""
     events = json.loads((Path(__file__).parent / "data" /
                          "trace_events_4chips.json").read_text())
     red = trace.reduce(events)

@@ -35,13 +35,12 @@ def test_minitron_dense_gate_on_four_chips_by_hand():
 def test_decode_step_sums_the_layers():
     conf = common.load_json(common.BENCH / "configs" /
                             "stablelm-1.6b-b80.json")
-    a = conf["arch"]
     step = work.decode_step(conf)
     per = [work.gemm(n, k, m, 8, conf["pruning"], 1)["params"] * c
-           for n, k, m, c in work.gemm_shapes(a)]
+           for n, k, m, c in work.gemm_shapes(conf)]
     assert step["params"] == sum(per)
     # about a fifth of the 1.44 B weight-GEMM parameters survive
-    dense = sum(k * m * c for _, k, m, c in work.gemm_shapes(a))
+    dense = sum(k * m * c for _, k, m, c in work.gemm_shapes(conf))
     assert dense == 24 * (4 * 2048 * 2048 + 3 * 2048 * 5632) + 2048 * 100352
     assert 0.19 < step["params"] / dense < 0.2
 
