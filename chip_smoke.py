@@ -25,7 +25,7 @@ One chip, one process, in this order:
                  shapes against a float32 ``jnp.dot``.
 
 ``--four-chips`` runs only the mesh phase: minitron-8b (32 layers, d 4096,
-about 20 GB in bf16 — more than one chip holds) created and compacted
+16.5 GB in bf16 — more than one chip holds) created and compacted
 straight into its 1x4 serving shardings, served dense and at
 ``--sparsity 0.5`` through the shard_map'd kernels, each compared by
 tokens and by a logit gap with the plain-XLA GSPMD engine on the same

@@ -37,6 +37,11 @@ class ModelConfig:
     act: str = "silu"
     norm_eps: float = 1e-5
     rope_theta: float = 10000.0
+    # norm kind: "rms" (RMS norm scaled by 1 + w) or "layernorm1p"
+    # (LayerNorm scaled by 1 + w with a bias; nemotron)
+    norm: str = "rms"
+    gated_mlp: bool = True      # False: act(x @ w_up) @ w_down, no w_gate
+    rotary_frac: float = 1.0    # leading share of each head's channels roped
     # hybrid (recurrentgemma): pattern of block kinds, tiled over depth
     block_pattern: Tuple[str, ...] = ()          # e.g. ("rec","rec","attn")
     lru_width: int = 0                           # 0 -> d_model

@@ -356,19 +356,38 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-5) -> jax.Array:
     return (x * (1.0 + scale.astype(jnp.float32))).astype(dt)
 
 
-def rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0
-         ) -> jax.Array:
+def layer_norm1p(x: jax.Array, scale: jax.Array, bias: jax.Array,
+                 eps: float = 1e-5) -> jax.Array:
+    """LayerNorm scaled by ``1 + scale`` plus ``bias`` (Nemotron's
+    ``LayerNorm1P``), computed in float32 like ``rms_norm``."""
+    dt = x.dtype
+    x = x.astype(jnp.float32)
+    mu = jnp.mean(x, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(x - mu), axis=-1, keepdims=True)
+    x = (x - mu) * jax.lax.rsqrt(var + eps)
+    return (x * (1.0 + scale.astype(jnp.float32))
+            + bias.astype(jnp.float32)).astype(dt)
+
+
+def rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0,
+         frac: float = 1.0) -> jax.Array:
     """Rotary embedding.  x: (..., seq, heads, head_dim), positions: (seq,)
-    or broadcastable to (..., seq)."""
+    or broadcastable to (..., seq).  Only the first ``frac * head_dim``
+    channels of each head rotate, with frequencies over that width (HF's
+    ``partial_rotary_factor``); the rest pass through unchanged."""
     hd = x.shape[-1]
-    half = hd // 2
+    rot = int(hd * frac)
+    half = rot // 2
     freqs = (1.0 / theta) ** (jnp.arange(0, half, dtype=jnp.float32) / half)
     ang = positions.astype(jnp.float32)[..., None] * freqs   # (..., seq, half)
     cos = jnp.cos(ang)[..., None, :]
     sin = jnp.sin(ang)[..., None, :]
-    x1, x2 = x[..., :half], x[..., half:]
+    x1, x2 = x[..., :half], x[..., half:rot]
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
-    return out.astype(x.dtype)
+    out = out.astype(x.dtype)
+    if rot < hd:
+        out = jnp.concatenate([out, x[..., rot:]], axis=-1)
+    return out
 
 
 def dense_init(key, in_dim: int, out_dim: int, dtype=jnp.float32,
@@ -434,6 +453,7 @@ def remat_fn(cfg, body: Callable) -> Callable:
 def act_fn(name: str) -> Callable[[jax.Array], jax.Array]:
     return {"silu": jax.nn.silu, "gelu": jax.nn.gelu, "relu": jax.nn.relu,
             "gelu_tanh": functools.partial(jax.nn.gelu, approximate=True),
+            "relu2": lambda x: jnp.square(jax.nn.relu(x)),
             }[name]
 
 

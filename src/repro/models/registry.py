@@ -68,7 +68,7 @@ def _tf_param_count(cfg: ModelConfig, active: bool) -> int:
         eff = cfg.moe.top_k if active else E
         ffn = D * E + eff * 3 * D * F
     else:
-        ffn = 3 * D * F
+        ffn = (3 if cfg.gated_mlp else 2) * D * F
     return cfg.num_layers * (attn + ffn)
 
 

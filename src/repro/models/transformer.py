@@ -26,9 +26,9 @@ from ..configs.base import ModelConfig
 from ..kernels.decode_attention import ops as live_kv
 from .attention import attention, decode_attention
 from .common import (act_fn, dense_init, execution_context, griffin_linear,
-                     layer_scan, length_mask, paged_view, paged_write,
-                     remat_fn, rms_norm, rope, stack_layers, take_last,
-                     write_kv_layer)
+                     layer_norm1p, layer_scan, length_mask, paged_view,
+                     paged_write, remat_fn, rms_norm, rope, stack_layers,
+                     take_last, write_kv_layer)
 from .moe import init_moe, moe_ffn
 
 Params = Dict[str, Any]
@@ -49,13 +49,17 @@ def _init_layer(cfg: ModelConfig, key) -> Params:
         "wv": dense_init(ks[2], D, KVH * hd, dt),
         "wo": dense_init(ks[3], H * hd, D, dt),
     }
+    if cfg.norm == "layernorm1p":
+        p["ln1_b"] = jnp.zeros((D,), dt)
+        p["ln2_b"] = jnp.zeros((D,), dt)
     if cfg.qk_norm:
         p["qn"] = jnp.zeros((hd,), dt)
         p["kn"] = jnp.zeros((hd,), dt)
     if cfg.moe:
         p["moe"] = init_moe(ks[4], D, cfg.d_ff, cfg.moe, dt)
     else:
-        p["w_gate"] = dense_init(ks[5], D, cfg.d_ff, dt)
+        if cfg.gated_mlp:
+            p["w_gate"] = dense_init(ks[5], D, cfg.d_ff, dt)
         p["w_up"] = dense_init(ks[6], D, cfg.d_ff, dt)
         p["w_down"] = dense_init(ks[7], cfg.d_ff, D, dt)
     return p
@@ -70,6 +74,8 @@ def init_params(cfg: ModelConfig, key) -> Params:
         "layers": stack_layers(functools.partial(_init_layer, cfg),
                                k_layers, cfg.num_layers),
     }
+    if cfg.norm == "layernorm1p":
+        params["final_norm_b"] = jnp.zeros((cfg.d_model,), dt)
     if not cfg.tie_embeddings:
         params["head"] = dense_init(k_head, cfg.d_model, cfg.vocab_size, dt)
     return params
@@ -83,6 +89,16 @@ def unembed(cfg: ModelConfig, params: Params) -> jax.Array:
 # blocks
 # ---------------------------------------------------------------------------
 
+def _norm(cfg: ModelConfig, p: Params, name: str, x: jax.Array) -> jax.Array:
+    """The block's norm ``name`` of ``p`` applied to ``x``: RMS norm, or
+    LayerNorm1p with the bias leaf ``<name>_b`` (``cfg.norm``)."""
+    if cfg.norm == "rms":
+        return rms_norm(x, p[name], cfg.norm_eps)
+    if cfg.norm == "layernorm1p":
+        return layer_norm1p(x, p[name], p[name + "_b"], cfg.norm_eps)
+    raise ValueError(f"unknown norm {cfg.norm!r}")
+
+
 def _ffn(cfg: ModelConfig, p: Params, x: jax.Array, decode: bool = False,
          valid=None) -> Tuple[jax.Array, jax.Array]:
     if cfg.moe:
@@ -92,8 +108,11 @@ def _ffn(cfg: ModelConfig, p: Params, x: jax.Array, decode: bool = False,
                            valid=None if valid is None
                            else valid.reshape(B * S))
         return out.reshape(B, S, D), aux
-    h = act_fn(cfg.act)(griffin_linear(x, p["w_gate"])) * \
-        griffin_linear(x, p["w_up"])
+    if cfg.gated_mlp:
+        h = act_fn(cfg.act)(griffin_linear(x, p["w_gate"])) * \
+            griffin_linear(x, p["w_up"])
+    else:
+        h = act_fn(cfg.act)(griffin_linear(x, p["w_up"]))
     return griffin_linear(h, p["w_down"]).astype(x.dtype), \
         jnp.zeros((), jnp.float32)
 
@@ -107,8 +126,8 @@ def _qkv(cfg: ModelConfig, p: Params, x: jax.Array, positions: jax.Array):
     if cfg.qk_norm:
         q = rms_norm(q, p["qn"], cfg.norm_eps)
         k = rms_norm(k, p["kn"], cfg.norm_eps)
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, positions, cfg.rope_theta)
+    q = rope(q, positions, cfg.rope_theta, cfg.rotary_frac)
+    k = rope(k, positions, cfg.rope_theta, cfg.rotary_frac)
     return q, k, v
 
 
@@ -119,14 +138,14 @@ def block_train(cfg: ModelConfig, p: Params, x: jax.Array,
     already keeps pads out of real positions (pads sit *after* every real
     token), so only the MoE dispatch needs it (pads must not consume expert
     capacity)."""
-    h = rms_norm(x, p["ln1"], cfg.norm_eps)
+    h = _norm(cfg, p, "ln1", x)
     q, k, v = _qkv(cfg, p, h, positions)
     with jax.named_scope("attention"):
         o = attention(q, k, v, causal=True, window=cfg.window,
                       kv_chunk=cfg.kv_chunk)
     B, S, _, _ = q.shape
     x = x + griffin_linear(o.reshape(B, S, -1), p["wo"]).astype(x.dtype)
-    h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
+    h2 = _norm(cfg, p, "ln2", x)
     f, aux = _ffn(cfg, p, h2, valid=valid)
     x = (x + f).astype(x.dtype)
     return (x, aux, (k, v)) if return_kv else (x, aux)
@@ -151,7 +170,7 @@ def block_decode(cfg: ModelConfig, p: Params, x: jax.Array, k_all, v_all,
     writes the new K/V and reads each row's valid positions only; a row
     the plan marks dead writes and reads nothing and attends to zeros.
     Without it ``decode_attention`` reads the layer's whole cache."""
-    h = rms_norm(x, p["ln1"], cfg.norm_eps)
+    h = _norm(cfg, p, "ln1", x)
     per_slot = pos.ndim > 0
     q, k, v = _qkv(cfg, p, h,
                    positions=pos[:, None] if per_slot else pos[None])
@@ -168,7 +187,7 @@ def block_decode(cfg: ModelConfig, p: Params, x: jax.Array, k_all, v_all,
                                  window=win)
     B = x.shape[0]
     x = x + griffin_linear(o.reshape(B, 1, -1), p["wo"]).astype(x.dtype)
-    h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
+    h2 = _norm(cfg, p, "ln2", x)
     f, _ = _ffn(cfg, p, h2, decode=True)
     return (x + f).astype(x.dtype), k_all, v_all
 
@@ -218,13 +237,22 @@ def _live_kv_lengths(cfg: ModelConfig, cache: Params, live):
 
 
 def kv_blocks(cfg: ModelConfig, cache: Params, live=None) -> jax.Array:
-    """(2,) int32: per layer, the KV blocks the next decode step's live-KV
-    attention reads on ``cache`` and the blocks its arena holds; zeros
-    where the step keeps ``decode_attention``.  ``live`` as for
-    ``decode_step``."""
+    """(2,) int32: per layer, the KV blocks (``BLOCK_S`` positions, the
+    last one partial where the arena is not whole blocks) the next decode
+    step's attention reads on ``cache`` and the blocks its arena holds.
+    The live-KV kernel reads the live rows' valid blocks; a fixed arena
+    that keeps ``decode_attention`` (on a mesh, or a head size the kernel
+    refuses) reads all of it, and so does a paged arena, whose
+    ``paged_view`` gathers every row's ``max_pages * page_size``
+    positions.  ``live`` as for ``decode_step``."""
     rows = _live_kv_lengths(cfg, cache, live)
     if rows is None:
-        return jnp.zeros((2,), jnp.int32)
+        if "pages" in cache:
+            B, max_pages = cache["pages"].shape
+            S = max_pages * cache["k"].shape[2]
+        else:
+            B, S = cache["k"].shape[1:3]
+        return jnp.full((2,), B * -(-S // live_kv.BLOCK_S), jnp.int32)
     return live_kv.kv_blocks(rows[0], cache["k"].shape[2])
 
 
@@ -240,7 +268,7 @@ def block_decode_paged(cfg: ModelConfig, p: Params, x: jax.Array, k_pool,
     (``pos < max_pages * page_size``) to: write at ``pos``, attend with
     ``window=None`` — bit-identical to :func:`block_decode` on the gathered
     view.  ``k_scale``/``v_scale`` are None for fp32 pools."""
-    h = rms_norm(x, p["ln1"], cfg.norm_eps)
+    h = _norm(cfg, p, "ln1", x)
     per_slot = pos.ndim > 0
     q, k, v = _qkv(cfg, p, h,
                    positions=pos[:, None] if per_slot else pos[None])
@@ -254,7 +282,7 @@ def block_decode_paged(cfg: ModelConfig, p: Params, x: jax.Array, k_pool,
         o = decode_attention(q, kc, vc, pos, window=None)
     B = x.shape[0]
     x = x + griffin_linear(o.reshape(B, 1, -1), p["wo"]).astype(x.dtype)
-    h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
+    h2 = _norm(cfg, p, "ln2", x)
     f, _ = _ffn(cfg, p, h2, decode=True)
     return (x + f).astype(x.dtype), k_pool, v_pool, k_scale, v_scale
 
@@ -286,7 +314,7 @@ def forward_hidden(cfg: ModelConfig, params: Params, tokens: jax.Array,
     fn = remat_fn(cfg, body)
     (x, aux), kvs = layer_scan(cfg.scan_layers, fn, (x, aux0),
                                params["layers"])
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    x = _norm(cfg, params, "final_norm", x)
     return (x, aux, kvs) if return_kv else (x, aux)
 
 
@@ -360,7 +388,7 @@ def decode_step(cfg: ModelConfig, params: Params, cache: Params,
         cfg.scan_layers, body,
         (x, cache["k"], cache["v"], jnp.zeros((), jnp.int32)),
         params["layers"])
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    x = _norm(cfg, params, "final_norm", x)
     logits = griffin_linear(x[:, 0], unembed(cfg, params))
     return logits, {"k": ks, "v": vs, "pos": pos}
 
@@ -381,7 +409,7 @@ def _decode_step_paged(cfg: ModelConfig, params: Params, cache: Params,
              cache.get("v_scale"), jnp.zeros((), jnp.int32))
     (x, kp, vp, ks_, vs_, _), _ = layer_scan(cfg.scan_layers, body, carry,
                                              params["layers"])
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    x = _norm(cfg, params, "final_norm", x)
     logits = griffin_linear(x[:, 0], unembed(cfg, params))
     out = {"pos": pos, "pages": pages, "k": kp, "v": vp}
     if int8:

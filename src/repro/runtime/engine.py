@@ -707,11 +707,12 @@ class ServeEngine:
         # on-device live count, so emitted == prefill_calls + live_rows);
         # prefill_tokens / prefill_padded_tokens: prompt tokens prefilled
         # and the bucket lengths they ran at; kv_blocks_read /
-        # kv_blocks_arena: per layer, the KV blocks the chunks' live-KV
-        # attention kernel reads and the blocks the arena holds over the
-        # same steps (the chunk's on-device ``ModelApi.kv_blocks`` sums,
-        # fetched in the tick's one sync; both 0 where the kernel does not
-        # run)
+        # kv_blocks_arena: per layer, the KV blocks the chunks' attention
+        # reads and the blocks the arena holds over the same steps (the
+        # chunk's on-device ``ModelApi.kv_blocks`` sums, fetched in the
+        # tick's one sync: the live rows' blocks under the live-KV kernel,
+        # the whole arena where a fixed arena keeps ``decode_attention``
+        # and on a paged arena, whose gathered view is the whole arena)
         self.stats = {"decode_steps": 0, "prefill_calls": 0, "emitted": 0,
                       "retraces": 0, "chunk_calls": 0, "host_syncs": 0,
                       "live_rows": 0, "prefill_tokens": 0,

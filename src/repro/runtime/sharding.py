@@ -48,7 +48,7 @@ _IN_OUT = ("wq", "wk", "wv", "w_gate", "w_up", "w_ff1", "w_x", "router",
            "head", "w_rg", "w_ig", "wz", "wi", "wf", "wo_gate")
 _OUT_IN = ("wo", "w_down", "w_ff2", "w_out")
 _REPLICATE = ("ln", "ln1", "ln2", "ln_x", "gn", "final_norm", "enc_norm",
-              "lam", "qn", "kn")
+              "lam", "qn", "kn", "ln1_b", "ln2_b", "final_norm_b")
 # GriffinWeights (block-compacted weights) pytree children.  The compacted
 # K axis (b_comp rows) is never sharded: kidx holds *global* K-block ids and
 # per-shard counts would diverge, so only the output (N) axis splits; the

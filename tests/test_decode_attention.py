@@ -82,8 +82,9 @@ def test_kernel_matches_decode_attention(case):
 def test_kv_blocks_counts_live_blocks():
     """``transformer.kv_blocks``: the next step's rows of lengths 0 (dead),
     1, 255, 256, 257 and 2048 (a position past the arena caps there) read
-    0 + 1 + 1 + 1 + 2 + 8 blocks of 256 and the arena holds 6 x 8; zeros
-    with kernels off or an arena that is not whole blocks."""
+    0 + 1 + 1 + 1 + 2 + 8 blocks of 256 and the arena holds 6 x 8.  With
+    kernels off, or an arena that is not whole blocks (its last block
+    partial), ``decode_attention`` reads the whole arena: 6 x 8 of 6 x 8."""
     cfg = get_config("stablelm-1.6b").reduced()
 
     def cache(S):                      # only the arena's shape is read
@@ -95,7 +96,7 @@ def test_kv_blocks_counts_live_blocks():
         part = transformer.kv_blocks(cfg, cache(2000), live)
     off = transformer.kv_blocks(cfg, cache(2048), live)
     assert list(np.asarray(got)) == [0 + 1 + 1 + 1 + 2 + 8, 6 * 8]
-    assert list(np.asarray(part)) == list(np.asarray(off)) == [0, 0]
+    assert list(np.asarray(part)) == list(np.asarray(off)) == [6 * 8, 6 * 8]
     assert ops.position_minor(64) and not ops.position_minor(128)
 
 

@@ -132,7 +132,9 @@ def test_jit_serve_fns_run_on_one_device_mesh():
     assert int(cache3["pos"]) == S + 2
     assert list(np.asarray(remaining)) == [0, 0]
     assert float(zd) == 3.0                     # one live row x three steps
-    assert list(np.asarray(kv)) == [0, 0]       # no live-KV kernel here
+    # no live-KV kernel here: decode_attention reads the whole arena,
+    # 2 rows x one (partial) 256-block, three steps
+    assert list(np.asarray(kv)) == [6, 6]
     assert chunk_for(3) is chunk_for(3)         # ladder memoized per length
 
 
@@ -740,8 +742,9 @@ def test_engine_live_kv_kernel_keeps_every_live_token(monkeypatch):
     monkeypatch.setattr(transformer, "runs_live_kv", lambda *a, **k: False)
     plain = ServeEngine(api, params, config=_kernel_config())
     want = plain.run(trace())
+    # decode_attention reads the whole arena: 3 slots x one 256-block
     assert plain.stats["kv_blocks_read"] == plain.stats["kv_blocks_arena"] \
-        == 0
+        == plain.stats["decode_steps"] * 3 > 0
     assert {r: o.tokens for r, o in outs.items()} == \
         {r: o.tokens for r, o in want.items()}
 
