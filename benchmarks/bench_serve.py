@@ -1,22 +1,14 @@
-"""Serving-throughput benchmark: static vs per-step vs fused-chunk decode.
+"""Serving benchmark on the CPU: the fused-chunk engine's counts.
 
 Drives the slot-pool engine (``repro.runtime.engine``) over a deterministic
-mixed prompt/gen-length request trace (reduced config, CPU-scale) under
-three configurations:
-
-  static        step-wise decode, admission only on a drained pool
-  continuous    step-wise decode (decode_chunk=1) — the PR 3 hot path:
-                one decode dispatch + argmax + host sync per token
-  continuous-chunked
-                the fused device-resident hot path (decode_chunk=8):
-                decode -> argmax -> feedback -> bookkeeping scanned on
-                device, one host sync per 8 steps (DESIGN.md Section 9)
-
-Engines sharing a decode_chunk share one memoized set of jitted fns and
-are timed on a warm second run, so the static/continuous gap is pure
-scheduling and the continuous/chunked gap is pure host-synchronization.
-The headline column is tok/s; ``host_syncs_per_token`` is the wall-clock-
-free twin the CI serve stage bounds.
+mixed prompt/gen-length request trace (reduced config, CPU-scale) with
+continuous admission and the fused device-resident decode chunk
+(``continuous-chunked``, decode_chunk=8: decode -> argmax -> feedback ->
+bookkeeping scanned on device, one host sync per chunk; DESIGN.md Section
+9).  The record's numbers that mean anything are counts — tokens per
+decode step and ``host_syncs_per_token``, which the CI serve stage bounds.
+``cpu_tok_s`` and ``cpu_wall_s`` are host wall clock of a CPU run, never a
+device metric; the chip benchmark is ``bench/``.
 
 ``--mesh DxM`` appends a mesh-parallel row (``runtime.mesh_serve
 .MeshServeEngine`` on a data x model device mesh, DESIGN.md Section 10)
@@ -60,16 +52,12 @@ SLOTS = 4
 CHUNK = 8
 PROMPT_LENS = (8, 16, 24)
 # heavy-tailed generation lengths (sampled uniformly from the tuple, so
-# repeats are weights): most requests are short, ~1 in 8 is a straggler —
-# the regime where a static group idles every slot on its longest member,
-# and long enough that the fused path sustains full 8-step chunks (the
+# repeats are weights): most requests are short, ~1 in 8 is a straggler,
+# long enough that the engine sustains full 8-step chunks (the
 # chunk-length ladder shortens chunks near each request's end)
 GEN_LENS = (12, 12, 16, 16, 24, 24, 32, 112)
-# (policy, decode_chunk, fused, mesh): fused=False is the preserved PR 3
-# per-step hot path — the baseline the acceptance criterion compares
-# against; mesh=None rows run the unsharded engine
-CONFIGS = (("static", 1, False, None), ("continuous", 1, False, None),
-           ("continuous", CHUNK, True, None))
+# (policy, decode_chunk, mesh): mesh=None rows run the unsharded engine
+CONFIGS = (("continuous", CHUNK, None),)
 
 # router overload row (DESIGN.md Section 13): a seeded 2x-overload bursty
 # heavy-tailed trace through 2 replicas, once behind the bounded EDF
@@ -133,20 +121,19 @@ def build_workload(n_req: int):
     return cfg, api, params, cache_len, trace
 
 
-def _name(policy: str, fused: bool, mesh=None) -> str:
-    base = f"{policy}-chunked" if fused else policy
+def _name(policy: str, mesh=None) -> str:
+    base = f"{policy}-chunked"
     return f"{base}@{mesh}" if mesh else base
 
 
 def make_engine(api, params, factory_cache, policy, cache_len, chunk,
-                fused, mesh=None, plan=None):
+                mesh=None):
     if mesh:
         from repro.launch.mesh import serve_mesh
         from repro.runtime.mesh_serve import MeshServeEngine
         return MeshServeEngine(api, params, mesh=serve_mesh(mesh),
                                num_slots=SLOTS, cache_len=cache_len,
-                               policy=policy, decode_chunk=chunk,
-                               fused=fused, plan=plan)
+                               policy=policy, decode_chunk=chunk)
 
     def factory():
         if chunk not in factory_cache:
@@ -156,7 +143,7 @@ def make_engine(api, params, factory_cache, policy, cache_len, chunk,
 
     return ServeEngine(api, params, num_slots=SLOTS, cache_len=cache_len,
                        policy=policy, fns_factory=factory,
-                       decode_chunk=chunk, fused=fused, plan=plan)
+                       decode_chunk=chunk)
 
 
 def run_router_overload(api, params, cache_len, cfg, n_req,
@@ -171,7 +158,7 @@ def run_router_overload(api, params, cache_len, cfg, n_req,
                           ("router-unbounded", False)):
         router = RouterEngine(
             lambda: make_engine(api, params, factory_cache, "continuous",
-                                cache_len, CHUNK, True),
+                                cache_len, CHUNK),
             ROUTER_REPLICAS,
             queue_bound=ROUTER_BOUND if bounded else None,
             degradation=DegradationConfig() if bounded else None)
@@ -187,7 +174,7 @@ def run_router_overload(api, params, cache_len, cfg, n_req,
             ticks=router.clock,
             ladder_history=[list(t) for t in router.ladder.history]
             if router.ladder else [],
-            wall_s=round(dt, 4), **summary)
+            cpu_wall_s=round(dt, 4), **summary)
         emit(f"serve/{ARCH}/{name}", dt * 1e6 / max(1, n_req),
              f"ttft_p99={summary['ttft_p99']};shed={summary['shed']};"
              f"depth={router.max_queue_depth}")
@@ -310,7 +297,7 @@ def run_paged(api, params, cfg, n_req: int) -> dict:
                              kv_rows=(npages * ps if page_size
                                       else slots * clen),
                              emitted=eng.stats["emitted"],
-                             wall_s=round(dt, 4))
+                             cpu_wall_s=round(dt, 4))
         emit(f"serve/{ARCH}/paged/{name}", dt * 1e6 / max(1, n_req),
              f"peak={peak};emitted={eng.stats['emitted']}")
     ratio = (results["paged-fp32"]["peak_concurrent"] /
@@ -341,20 +328,12 @@ def run_paged(api, params, cfg, n_req: int) -> dict:
             "int8_tol": PAGED_INT8_TOL}
 
 
-def run(fast: bool = True, json_out: bool = False,
-        mesh: str = None, plan_path: str = None) -> None:
+def run(fast: bool = True, json_out: bool = False, mesh: str = None) -> None:
     n_req = 16 if fast else 48
     cfg, api, params, cache_len, trace = build_workload(n_req)
-    # a tuned kernel plan (repro.launch.autotune, DESIGN.md Section 12)
-    # only moves this dense workload's Mode thresholds — rows record which
-    # plan (if any) was active so the perf trajectory stays attributable
-    fam_plan = None
-    if plan_path:
-        from repro.tuning import load_plan
-        fam_plan = load_plan(plan_path).family(cfg.family)
     configs = list(CONFIGS)
     if mesh and mesh != "1x1":
-        configs.append(("continuous", CHUNK, True, mesh))
+        configs.append(("continuous", CHUNK, mesh))
     factory_cache: dict = {}
     rows = []
     results = {}
@@ -367,17 +346,17 @@ def run(fast: bool = True, json_out: bool = False,
     # from landing on one config's entire sample (the per-trace step/sync
     # counts are deterministic either way).
     engines, warm_retraces, best = {}, {}, {}
-    for policy, chunk, fused, cmesh in configs:
-        name = _name(policy, fused, cmesh)
+    for policy, chunk, cmesh in configs:
+        name = _name(policy, cmesh)
         eng = make_engine(api, params, factory_cache, policy, cache_len,
-                          chunk, fused, cmesh, plan=fam_plan)
+                          chunk, cmesh)
         eng.run(trace())
         engines[name] = eng
         warm_retraces[name] = eng.stats["retraces"]
         best[name] = float("inf")
     for _ in range(3):
-        for policy, chunk, fused, cmesh in configs:
-            name = _name(policy, fused, cmesh)
+        for policy, chunk, cmesh in configs:
+            name = _name(policy, cmesh)
             eng = engines[name]
             eng.stats = {k: 0 for k in eng.stats}
             t0 = time.perf_counter()
@@ -385,8 +364,8 @@ def run(fast: bool = True, json_out: bool = False,
             best[name] = min(best[name], time.perf_counter() - t0)
             assert len(outs) == n_req and all(o.finished >= 0
                                               for o in outs.values())
-    for policy, chunk, fused, cmesh in configs:
-        name = _name(policy, fused, cmesh)
+    for policy, chunk, cmesh in configs:
+        name = _name(policy, cmesh)
         eng, dt = engines[name], best[name]
         toks = eng.stats["emitted"]
         tok_s = toks / dt
@@ -394,52 +373,37 @@ def run(fast: bool = True, json_out: bool = False,
         syncs_tok = eng.stats["host_syncs"] / toks
         results[name] = dict(
             policy=policy, decode_chunk=chunk, requests=n_req, slots=SLOTS,
-            mesh=cmesh or "1x1", plan=plan_path,
-            emitted=toks, decode_steps=eng.stats["decode_steps"],
+            mesh=cmesh or "1x1", emitted=toks, decode_steps=eng.stats["decode_steps"],
             chunk_calls=eng.stats["chunk_calls"],
             prefill_calls=eng.stats["prefill_calls"],
             prefill_buckets=sorted(eng.prefill_buckets),
             retraces=warm_retraces[name],
             host_syncs_per_token=round(syncs_tok, 4),
-            wall_s=round(dt, 4), tok_s=round(tok_s, 1),
+            cpu_wall_s=round(dt, 4), cpu_tok_s=round(tok_s, 1),
             tok_per_step=round(tok_step, 3))
         emit(f"serve/{ARCH}/{name}", dt * 1e6 / toks,
-             f"tok_s={tok_s:.1f};tok_per_step={tok_step:.2f};"
+             f"cpu_tok_s={tok_s:.1f};tok_per_step={tok_step:.2f};"
              f"syncs_per_tok={syncs_tok:.3f};"
              f"decode_steps={eng.stats['decode_steps']}")
         rows.append({"config": name, "mesh": cmesh or "1x1",
-                     "plan": plan_path or "",
                      "requests": n_req, "slots": SLOTS,
                      "emitted": toks, "decode_chunk": chunk,
                      "decode_steps": eng.stats["decode_steps"],
                      "prefill_calls": eng.stats["prefill_calls"],
                      "host_syncs_per_token": round(syncs_tok, 4),
-                     "wall_s": round(dt, 4), "tok_s": round(tok_s, 1),
+                     "cpu_wall_s": round(dt, 4),
+                     "cpu_tok_s": round(tok_s, 1),
                      "tok_per_step": round(tok_step, 3)})
-    sched_speedup = (results["continuous"]["tok_s"] /
-                     results["static"]["tok_s"])
-    fused_speedup = (results["continuous-chunked"]["tok_s"] /
-                     results["continuous"]["tok_s"])
-    blank = {"mesh": "", "plan": "", "requests": n_req, "slots": SLOTS,
-             "emitted": "",
-             "decode_chunk": "", "decode_steps": "", "prefill_calls": "",
-             "host_syncs_per_token": "", "wall_s": "", "tok_per_step": ""}
-    rows.append({"config": "continuous/static",
-                 "tok_s": round(sched_speedup, 3), **blank})
-    rows.append({"config": "chunked/continuous",
-                 "tok_s": round(fused_speedup, 3), **blank})
     path = write_csv("bench_serve", rows)
-    print(f"# bench_serve -> {path} (continuous/static tok/s = "
-          f"{sched_speedup:.2f}x, chunked/continuous tok/s = "
-          f"{fused_speedup:.2f}x)")
+    print(f"# bench_serve -> {path}")
     mesh_speedups = {}
     if mesh and mesh != "1x1":
-        sh = results[_name("continuous", True, mesh)]
+        sh = results[_name("continuous", mesh)]
         un = results["continuous-chunked"]
         assert sh["tok_per_step"] == un["tok_per_step"], \
             "mesh sharding changed tokens/step — scheduling is no longer " \
             "placement-invariant"
-        tok_s_ratio = sh["tok_s"] / un["tok_s"]
+        tok_s_ratio = sh["cpu_tok_s"] / un["cpu_tok_s"]
         mesh_speedups = {
             "sharded_vs_unsharded_tok_s": round(tok_s_ratio, 3),
             "sharded_tok_per_step_ratio":
@@ -466,14 +430,12 @@ def run(fast: bool = True, json_out: bool = False,
     if json_out:
         out = {
             "arch": ARCH, "backend": jax.default_backend(),
-            "mesh": mesh or "1x1", "plan": plan_path,
+            "mesh": mesh or "1x1",
             "trace": {"requests": n_req, "slots": SLOTS,
                       "prompt_lens": list(PROMPT_LENS),
                       "gen_lens": list(GEN_LENS), "seed": 7},
             "configs": results,
-            "speedups": {"continuous_vs_static": round(sched_speedup, 3),
-                         "chunked_vs_continuous": round(fused_speedup, 3),
-                         **mesh_speedups},
+            "speedups": mesh_speedups,
             "router": {"trace": {"requests": n_req, "seed": 11,
                                  "arrival_process": "bursty",
                                  "length_dist": "heavy",
@@ -496,10 +458,5 @@ if __name__ == "__main__":
                     help="append a mesh-parallel engine row (needs D*M "
                          "devices; on CPU export XLA_FLAGS=--xla_force_"
                          "host_platform_device_count=8)")
-    ap.add_argument("--plan", default=None, metavar="PATH",
-                    help="tuned kernel plan (repro.launch.autotune); rows "
-                         "record it and every engine serves under its "
-                         "thresholds")
     args = ap.parse_args()
-    run(fast=not args.full, json_out=args.json, mesh=args.mesh,
-        plan_path=args.plan)
+    run(fast=not args.full, json_out=args.json, mesh=args.mesh)

@@ -190,7 +190,7 @@ def near_tie(ref_logits, a: int, b: int) -> float:
 def kernel_text(engine) -> str:
     """Compiled text of the engine's full-length fused decode chunk."""
     with engine._scope():
-        fn = engine._fns()[2](engine.decode_chunk)
+        fn = engine._fns()[1](engine.decode_chunk)
         return fn.lower(engine.params, engine.cache, engine._tokens,
                         engine._remaining).compile().as_text()
 

@@ -40,14 +40,6 @@ Configs whose ``mesh`` needs more devices than this process has are
 skipped with a note (the CI sharded job runs with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8``).
 
-When ``benchmarks/out/BENCH_autotune.json`` is committed (the
-``bench_autotune --json`` record, DESIGN.md Section 12), the committed
-kernel plan is additionally replayed per family against the frozen
-defaults: tuned/default tok-per-step ratio must be >= 1.0 and match the
-record (a plan never changes the decode schedule), and tuned tokens must
-be identical to default tokens (the plan-parity contract).  Tuned tok/s
-is the *recorded* headline but is not wall-clock-gated here.
-
 Run from the repo root (scripts/ci.sh bench-regression stage):
 
   PYTHONPATH=src python scripts/check_bench_regression.py
@@ -65,66 +57,13 @@ sys.path.insert(0, str(ROOT))
 SYNC_SLACK = 0.02
 
 
-def check_autotune(failures: list) -> int:
-    """Replay the committed autotune record: tuned-vs-default tok/step
-    ratio (deterministic) and token identity, per family.  Returns the
-    number of families checked (0 when no record is committed)."""
-    from repro.sparsity import sparsify_params
-    from repro.tuning import load_plan
-    from repro.tuning.measure import PRUNE, measure_plan, tuning_workload
-
-    jpath = ROOT / "benchmarks" / "out" / "BENCH_autotune.json"
-    if not jpath.exists():
-        print("skip autotune gate: BENCH_autotune.json not committed")
-        return 0
-    rec = json.loads(jpath.read_text())
-    plan = load_plan(str(ROOT / rec["plan"]))
-    t = rec["tune"]
-    checked = 0
-    for family, row in rec["families"].items():
-        fp = plan.family(family)
-        if fp is None:
-            failures.append(f"autotune/{family}: committed plan "
-                            f"{rec['plan']} has no entry for this family")
-            continue
-        _, api, params, cache_len, trace = tuning_workload(
-            family, requests=t["requests"])
-        base = measure_plan(
-            api, sparsify_params(params, t["sparsity"], compact=True,
-                                 **PRUNE),
-            cache_len, trace, repeats=1)
-        tuned = measure_plan(
-            api, sparsify_params(params, t["sparsity"], compact=True,
-                                 plan=fp, **PRUNE),
-            cache_len, trace, plan=fp, repeats=1)
-        checked += 1
-        if tuned["tokens"] != base["tokens"]:
-            failures.append(f"autotune/{family}: tuned tokens diverged "
-                            "from default — the committed plan changes "
-                            "what GEMMs compute")
-        ratio = tuned["tok_per_step"] / base["tok_per_step"]
-        if ratio < 1.0 - 1e-9:
-            failures.append(
-                f"autotune/{family}: tuned/default tok-per-step ratio "
-                f"{ratio:.3f} < 1.0 — the plan degraded the decode "
-                "schedule")
-        if abs(ratio - row["tok_per_step_ratio"]) > 1e-6:
-            failures.append(
-                f"autotune/{family}: tok-per-step ratio drifted "
-                f"{row['tok_per_step_ratio']} -> {ratio:.3f}")
-        print(f"autotune/{family}: winner={row['winner']} tok/step ratio="
-              f"{ratio:.3f} (recorded {row['tok_per_step_ratio']}), "
-              f"tokens identical={tuned['tokens'] == base['tokens']}")
-    return checked
-
-
 def check_router(rec, api, params, cache_len, cfg, n_req, factory_cache,
                  failures) -> int:
     """Replay the committed router overload rows (DESIGN.md Section 13).
     Every gated field is in virtual router ticks — deterministic given
     the recorded trace seed — so shed counts, queue depth, p50/p99 TTFT,
     inter-token latency and SLO attainment must match with ``==``
-    (wall_s/ticks stay ungated).  Returns rows checked (0 = no router
+    (cpu_wall_s/ticks stay ungated).  Returns rows checked (0 = no router
     section committed)."""
     from benchmarks.bench_serve import run_router_overload
 
@@ -164,7 +103,7 @@ def check_paged(rec, api, params, cfg, failures) -> int:
     within tolerance); here the replay is additionally compared field by
     field against the record — peak concurrency, emitted tokens and the
     token-identity flags are deterministic over the recorded seed, so
-    they must match with ``==`` (wall_s and the float logit gap stay
+    they must match with ``==`` (cpu_wall_s and the float logit gap stay
     ungated beyond the recorded tolerance).  Returns rows checked (0 =
     no paged section committed)."""
     from benchmarks.bench_serve import run_paged
@@ -228,10 +167,6 @@ def main() -> int:
     failures, checked = [], 0
     factory_cache: dict = {}
     replayed_tps: dict = {}
-    fam_plan = None
-    if rec.get("plan"):
-        from repro.tuning import load_plan
-        fam_plan = load_plan(str(ROOT / rec["plan"])).family(cfg.family)
     for name, c in rec["configs"].items():
         mesh = c.get("mesh", "1x1")
         if mesh != "1x1":
@@ -240,10 +175,9 @@ def main() -> int:
                 print(f"skip {name}: mesh {mesh} needs {d * m} devices, "
                       f"have {n_dev}")
                 continue
-        fused = c["decode_chunk"] > 1
         eng = make_engine(api, params, factory_cache, c["policy"],
-                          cache_len, c["decode_chunk"], fused,
-                          None if mesh == "1x1" else mesh, plan=fam_plan)
+                          cache_len, c["decode_chunk"],
+                          None if mesh == "1x1" else mesh)
         outs = eng.run(trace())
         assert len(outs) == n_req and all(o.finished >= 0
                                           for o in outs.values())
@@ -294,14 +228,11 @@ def main() -> int:
 
     paged_checked = check_paged(rec, api, params, cfg, failures)
 
-    tuned_checked = check_autotune(failures)
-
     for f in failures:
         print("FAIL:", f)
     print(f"check_bench_regression: {checked} configs + {router_checked} "
           f"router rows + {paged_checked} paged rows replayed against "
-          f"{jpath.name} + {tuned_checked} autotuned families, "
-          f"{len(failures)} drifts")
+          f"{jpath.name}, {len(failures)} drifts")
     if checked == 0:
         print("FAIL: no configs replayed")
         return 1

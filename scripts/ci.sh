@@ -15,10 +15,9 @@
 #                     paged parity matrix, int8 tolerance gate, CLI smokes
 #   serve             fused-chunk serve smoke + parity + sync budget
 #   bench-regression  fresh run vs committed BENCH_serve.json invariants
-#   serve-bench       static / per-step / fused-chunk benchmark smoke
+#   serve-bench       fused-chunk serve benchmark smoke (CPU counts)
 #   fig5              batched-sweep benchmark smoke (results cache)
 #   e2e               registry models through the substrate (smoke)
-#   autotune          tiny-budget kernel-plan pipeline smoke (2 families)
 #   docs              DESIGN.md citation check
 #   router            SLO router: unit tier + replica-kill chaos cells +
 #                     seeded 2x-overload smoke (single device)
@@ -83,7 +82,7 @@ stage_bench_regression() {
 }
 
 stage_serve_bench() {
-    echo "== serve bench: static / per-step (PR 3) / fused-chunk decode"
+    echo "== serve bench: fused-chunk decode counts on the CPU"
     # smoke-mode run: rewrites bench_serve.csv with 16-request rows (the
     # clean stage restores it); the committed BENCH_serve.json perf record
     # is only written by `bench_serve --full --json` and never touched here
@@ -98,30 +97,6 @@ stage_fig5() {
 stage_e2e() {
     echo "== e2e smoke: registry models through the mode-dispatched substrate"
     run python -m benchmarks.bench_e2e --smoke
-}
-
-stage_autotune() {
-    echo "== autotune smoke: tiny-budget DSE-in-the-loop tuning over 2"
-    echo "==   families (DESIGN.md Section 12) — a plan file must be"
-    echo "==   emitted, reload through the schema check, and candidate"
-    echo "==   token parity is asserted inside the pipeline; then the"
-    echo "==   committed plan serves a reduced model with oracle parity"
-    # the smoke plan lives under the gitignored benchmarks/out/ so the
-    # clean stage stays green; the committed kernel_plan.json is only
-    # written by `bench_autotune --json` and never touched here
-    run python -m repro.launch.autotune --families dense,ssm \
-        --budget 4 --shortlist 1 --requests 3 --repeats 1 \
-        --out benchmarks/out/plan_smoke.json
-    run python -c "
-from repro.tuning import load_plan
-p = load_plan('benchmarks/out/plan_smoke.json')
-assert {'dense', 'ssm'} <= set(p.families), sorted(p.families)
-print('plan_smoke.json loads: families', sorted(p.families),
-      'schema v%d' % p.schema_version)
-"
-    rm -f benchmarks/out/plan_smoke.json
-    run python -m repro.launch.serve --reduced --requests 4 --use-kernels \
-        --plan benchmarks/out/kernel_plan.json --parity
 }
 
 stage_docs() {
@@ -154,8 +129,7 @@ stage_mesh() {
     (
         export XLA_FLAGS="--xla_force_host_platform_device_count=8 ${XLA_FLAGS:-}"
         run python -m pytest -x -q -m mesh \
-            tests/test_shard_map_kernels.py tests/test_mesh_serve.py \
-            tests/test_autotune.py
+            tests/test_shard_map_kernels.py tests/test_mesh_serve.py
         run python examples/sparse_serve.py --mesh 2x4 --use-kernels
         run python examples/sparse_serve.py --mesh 2x2 --use-kernels \
             --spmd-fallback
@@ -191,7 +165,7 @@ stage_clean() {
 }
 
 ALL_STAGES="tier1 kernel tier2 paged serve bench-regression serve-bench \
-fig5 e2e autotune docs router mesh chaos clean"
+fig5 e2e docs router mesh chaos clean"
 STAGES="${*:-$ALL_STAGES}"
 for s in $STAGES; do
     case "$s" in
@@ -204,7 +178,6 @@ for s in $STAGES; do
         serve-bench) stage_serve_bench ;;
         fig5) stage_fig5 ;;
         e2e) stage_e2e ;;
-        autotune) stage_autotune ;;
         docs) stage_docs ;;
         router) stage_router ;;
         mesh) stage_mesh ;;

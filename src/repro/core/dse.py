@@ -37,13 +37,10 @@ from .workloads import category_workloads
 # model or workload tables cold-starts the cache automatically.
 CACHE_VERSION = 1
 
-# Version of the candidate-config / kernel-plan schema (repro.tuning,
-# DESIGN.md Section 12).  It is part of every sweep fingerprint: a schema
-# bump (candidate fields gaining new semantics) must cold-start the cache,
-# otherwise stale ``benchmarks/out/cache/`` rows written under the old
-# schema would be served verbatim to plan-era queries.  ``repro.tuning``
-# re-exports this as the plan's ``schema_version`` so the two can never
-# drift apart.
+# Version of the candidate-config schema.  It is part of every sweep
+# fingerprint: a schema bump (candidate fields gaining new semantics) must
+# cold-start the cache, otherwise stale ``benchmarks/out/cache/`` rows
+# written under the old schema would be served verbatim to new queries.
 CONFIG_SCHEMA_VERSION = 2
 
 _MODEL_DIGEST: Optional[str] = None

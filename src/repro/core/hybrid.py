@@ -35,11 +35,11 @@ def select_mode(a_sparsity: float, b_sparsity: float,
     """Pick the execution mode from declared/measured tensor sparsities.
 
     ``threshold`` gates the A side (and the B side too unless
-    ``b_threshold`` overrides it separately).  Tuned kernel plans
-    (repro.tuning, DESIGN.md Section 12) raise/lower these per family: the
-    thresholds change *which* kernel runs, never what it computes — skipped
-    blocks are exactly zero either way — so any threshold keeps greedy
-    decode token-identical.
+    ``b_threshold`` overrides it separately; the serving engine's
+    degradation ladder zeroes it to force Sparse.B on any pruned weight).
+    A threshold changes *which* kernel runs, never what it computes —
+    skipped blocks are exactly zero either way — so any threshold keeps
+    greedy decode token-identical.
     """
     b_thr = threshold if b_threshold is None else b_threshold
     return Mode.of(a_sparsity > threshold, b_sparsity > b_thr)

@@ -55,12 +55,6 @@ class GriffinWeights:
     n: int                   # original N (unpadded)
     block_k: int
     block_n: int
-    # Per-GEMM Mode-selection threshold override from a tuned kernel plan
-    # (repro.tuning, DESIGN.md Section 12): when set, griffin_linear passes
-    # it as ``select_mode``'s A threshold for this GEMM instead of the
-    # scope-wide one.  A meta field (trace-time constant): the threshold
-    # picks *which* kernel configuration runs, never what it computes.
-    a_thr: Optional[float] = None
 
     @property
     def density(self) -> float:
@@ -92,7 +86,7 @@ class GriffinWeights:
 jax.tree_util.register_dataclass(
     GriffinWeights,
     data_fields=["b_comp", "kidx", "cnt", "inv_perm"],
-    meta_fields=["k", "n", "block_k", "block_n", "a_thr"])
+    meta_fields=["k", "n", "block_k", "block_n"])
 
 
 def balance_columns(w_padded: np.ndarray, block_k: int, block_n: int,
@@ -177,9 +171,8 @@ def stack_weights(gws: Sequence[GriffinWeights]) -> GriffinWeights:
     assert gws, "empty stack"
     g0 = gws[0]
     for g in gws[1:]:
-        assert (g.k, g.n, g.block_k, g.block_n, g.a_thr) == \
-            (g0.k, g0.n, g0.block_k, g0.block_n, g0.a_thr), \
-            "heterogeneous stack"
+        assert (g.k, g.n, g.block_k, g.block_n) == \
+            (g0.k, g0.n, g0.block_k, g0.block_n), "heterogeneous stack"
         assert (g.inv_perm is None) == (g0.inv_perm is None), \
             "mixed balanced/unbalanced stack"
     max_cnt = max(g.kidx.shape[-1] for g in gws)
@@ -203,8 +196,7 @@ def stack_weights(gws: Sequence[GriffinWeights]) -> GriffinWeights:
         cnt=np.stack([np.asarray(g.cnt) for g in gws]),
         inv_perm=(None if g0.inv_perm is None
                   else np.stack([np.asarray(g.inv_perm) for g in gws])),
-        k=g0.k, n=g0.n, block_k=g0.block_k, block_n=g0.block_n,
-        a_thr=g0.a_thr)
+        k=g0.k, n=g0.n, block_k=g0.block_k, block_n=g0.block_n)
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "dual", "interpret",

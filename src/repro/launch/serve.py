@@ -40,7 +40,6 @@ from repro.runtime.serve import greedy_generate, jit_serve_fns
 from repro.runtime.slo import DegradationConfig
 from repro.runtime.straggler import StragglerConfig, StragglerDetector
 from repro.sparsity import sparsify_params
-from repro.tuning import load_plan
 
 
 def _lens(spec: str):
@@ -82,7 +81,7 @@ def _fault_hooks(args, devices, num_hosts):
     return injector, detector
 
 
-def build_engine(api, params, args, mesh, plan=None, econf=None) -> ServeEngine:
+def build_engine(api, params, args, mesh, econf=None) -> ServeEngine:
     """Engine from an ``EngineConfig`` (runtime.config) — the one
     construction path for both the unsharded and mesh-parallel engines.
     ``econf=None`` derives it from the CLI namespace (every flag explicit);
@@ -106,8 +105,7 @@ def build_engine(api, params, args, mesh, plan=None, econf=None) -> ServeEngine:
         injector, detector = _fault_hooks(
             args, list(smesh.devices.flat), smesh.devices.shape[0])
         return MeshServeEngine(api, params, mesh=smesh, config=econf,
-                               fault_injector=injector, straggler=detector,
-                               plan=plan)
+                               fault_injector=injector, straggler=detector)
     injector, detector = _fault_hooks(args, jax.devices(), 1)
     fns = None
     if econf.arena.page_size is None:
@@ -118,7 +116,7 @@ def build_engine(api, params, args, mesh, plan=None, econf=None) -> ServeEngine:
         fns = lambda: jit_serve_fns(api, mesh, ns, cl, params=params,
                                     decode_chunk=dc)
     return ServeEngine(api, params, config=econf, fns_factory=fns,
-                       fault_injector=injector, straggler=detector, plan=plan)
+                       fault_injector=injector, straggler=detector)
 
 
 def _print_slo(rows, summary) -> None:
@@ -138,8 +136,7 @@ def _print_slo(rows, summary) -> None:
           f"attainment {summary['slo_attainment']}")
 
 
-def _run_router(api, params, args, mesh, cfg, fam_plan, reqs,
-                econf=None) -> None:
+def _run_router(api, params, args, mesh, cfg, reqs, econf=None) -> None:
     """Multi-replica path (DESIGN.md Section 13): N engines behind the
     SLO-aware router.  A 'replica:' --inject-fault spec is consumed at
     the router level; kill/delay specs keep arming replica 0's internal
@@ -154,8 +151,7 @@ def _run_router(api, params, args, mesh, cfg, fam_plan, reqs,
     engines = []     # build eagerly so replica 0 reports its config once
 
     def make_engine():
-        eng = build_engine(api, params, args, mesh, plan=fam_plan,
-                           econf=econf)
+        eng = build_engine(api, params, args, mesh, econf=econf)
         engines.append(eng)
         return eng
 
@@ -305,8 +301,7 @@ def build_parser() -> argparse.ArgumentParser:
                     default="continuous")
     ap.add_argument("--measure-every", type=int, default=8)
     ap.add_argument("--decode-chunk", type=int, default=8,
-                    help="fused decode steps per host round-trip (1 = the "
-                         "per-step PR 3 hot path)")
+                    help="fused decode steps per host round-trip")
     ap.add_argument("--max-syncs-per-token", type=float, default=0.0,
                     help="assert host_syncs/token <= this after the run "
                          "(0 disables; the scripts/ci.sh serve stage "
@@ -323,12 +318,6 @@ def build_parser() -> argparse.ArgumentParser:
                          "instead of the shard_map'd Pallas kernels (the "
                          "parity baseline; scripts/ci.sh smokes it to keep "
                          "the oracle alive)")
-    ap.add_argument("--plan", default=None, metavar="PATH",
-                    help="tuned kernel plan JSON (repro.launch.autotune, "
-                         "DESIGN.md Section 12): this model family's entry "
-                         "steers weight-compaction granularity and Mode-"
-                         "selection thresholds; token output is unchanged "
-                         "by construction")
     ap.add_argument("--parity", action="store_true",
                     help="assert engine tokens == greedy_generate per "
                          "request")
@@ -391,13 +380,12 @@ class Prepared:
     """What ``main`` serves from: the parsed flags and resolved engine
     config, the model, its params (block-pruned and compacted when
     ``--sparsity`` asks, placed in the serving layout when ``--mesh``
-    asks), the kernel plan and the request trace."""
+    asks) and the request trace."""
     args: argparse.Namespace
     econf: EngineConfig
     cfg: object
     api: object
     params: object
-    fam_plan: object
     mesh: object
     reqs: list
 
@@ -423,8 +411,6 @@ def prepare(argv=None) -> Prepared:
     args.shed_policy = econf.router.shed_policy
     if args.inject_fault is None:
         args.inject_fault = econf.fault.inject
-    if args.plan is None:
-        args.plan = econf.kernels.plan
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -435,20 +421,12 @@ def prepare(argv=None) -> Prepared:
     params = (init_params_sharded(api, serve_mesh(econf.mesh), key)
               if econf.mesh else api.init(key))
 
-    fam_plan = None
-    if args.plan:
-        fam_plan = load_plan(args.plan).family(cfg.family)
-        if fam_plan is None:
-            print(f"plan {args.plan} has no entry for family "
-                  f"{cfg.family!r}; serving with defaults")
-
     if args.sparsity > 0:
         # Sparse.B preprocessing: offline block pruning of the GEMM weights
         prune = (dict(block_k=16, block_n=16, unit=8) if args.reduced
                  else dict())
         params = sparsify_params(params, args.sparsity,
-                                 compact=args.use_kernels, plan=fam_plan,
-                                 **prune)
+                                 compact=args.use_kernels, **prune)
 
     ttft_slo, slack_slo = _parse_slo(args.slo) if args.slo else (None, None)
     max_gen = None
@@ -464,22 +442,20 @@ def prepare(argv=None) -> Prepared:
                            length_dist=args.length_dist, max_gen=max_gen,
                            priorities=_lens(args.priorities),
                            deadline_slack=slack_slo, ttft_deadline=ttft_slo)
-    return Prepared(args, econf, cfg, api, params, fam_plan, mesh, reqs)
+    return Prepared(args, econf, cfg, api, params, mesh, reqs)
 
 
 def main(argv=None) -> None:
     enable_compile_cache()
     prep = prepare(argv)
-    args, econf, cfg, api, params, fam_plan, mesh, reqs = (
-        prep.args, prep.econf, prep.cfg, prep.api, prep.params,
-        prep.fam_plan, prep.mesh, prep.reqs)
+    args, econf, cfg, api, params, mesh, reqs = (
+        prep.args, prep.econf, prep.cfg, prep.api, prep.params, prep.mesh,
+        prep.reqs)
     if args.replicas > 0:
-        _run_router(api, params, args, mesh, cfg, fam_plan, reqs,
-                    econf=econf)
+        _run_router(api, params, args, mesh, cfg, reqs, econf=econf)
         return
 
-    engine = build_engine(api, params, args, mesh, plan=fam_plan,
-                          econf=econf)
+    engine = build_engine(api, params, args, mesh, econf=econf)
     arena = "fixed"
     if engine._paged is not None:
         arena = (f"paged ps={engine._paged.page_size} "

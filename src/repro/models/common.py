@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..configs.platform import kernel_interpret
-from ..core.hybrid import SPARSE_THRESHOLD, select_mode
+from ..core.hybrid import select_mode
 from ..core.spec import Mode
 from ..kernels.dense_gemm import ops as _dense_ops
 from ..kernels.dense_gemm.ops import dense_matmul
@@ -62,12 +62,6 @@ class SparseExecution:
     block_m: int = 128
     spmd_mesh: Optional[Any] = None
     spmd_kernels: bool = True
-    # Mode-selection A threshold per GEMM (``select_mode``'s first gate).
-    # Tuned kernel plans override it per family (``ServeEngine(plan=...)``)
-    # and per GEMM (a compacted leaf's ``GriffinWeights.a_thr`` wins over
-    # the scope) — a trace-time constant like everything else here, so it
-    # survives ``shard_map`` on meshes unchanged (DESIGN.md Section 12).
-    a_threshold: float = SPARSE_THRESHOLD
 
 
 _EXEC_STACK = [SparseExecution()]
@@ -77,8 +71,7 @@ _EXEC_STACK = [SparseExecution()]
 def sparse_execution(use_kernels: bool = True, interpret: bool = False,
                      a_sparsity: float = 0.0, block_m: int = 128,
                      spmd_mesh: Optional[Any] = None,
-                     spmd_kernels: bool = True,
-                     a_threshold: float = SPARSE_THRESHOLD):
+                     spmd_kernels: bool = True):
     """Scope under which ``griffin_linear`` dispatches to the Pallas
     kernels (mode per GEMM via ``core.hybrid.select_mode``).
 
@@ -93,8 +86,7 @@ def sparse_execution(use_kernels: bool = True, interpret: bool = False,
                                        a_sparsity=a_sparsity,
                                        block_m=block_m,
                                        spmd_mesh=spmd_mesh,
-                                       spmd_kernels=spmd_kernels,
-                                       a_threshold=a_threshold))
+                                       spmd_kernels=spmd_kernels))
     try:
         yield _EXEC_STACK[-1]
     finally:
@@ -110,10 +102,6 @@ def sparse_execution(use_kernels: bool = True, interpret: bool = False,
 #   "shard_map"   shard_map'd Pallas kernels under an spmd_mesh scope
 #   "spmd_oracle" the decompaction / dense-product SPMD oracles
 #   "plain"       plain jnp dots (no kernel requested)
-# plus one orthogonal outcome bucket: "dual" counts GriffinWeights GEMMs
-# whose Mode decision came out AB (dual predication on) — what a tuned
-# plan's a_threshold flips, so the plan tier can assert a threshold
-# actually changed select_mode outcomes (DESIGN.md Section 12).
 KERNEL_DISPATCH: Dict[str, int] = {}
 
 
@@ -185,12 +173,9 @@ def griffin_linear(x: jax.Array, w) -> jax.Array:
         x = _replicated(x, mesh)
     if isinstance(w, GriffinWeights):
         lead = x.shape[:-1]
-        thr = w.a_thr if w.a_thr is not None else ctx.a_threshold
-        mode = select_mode(ctx.a_sparsity, 1.0, threshold=thr)
+        mode = select_mode(ctx.a_sparsity, 1.0)
         x2 = x.reshape(-1, x.shape[-1])
         dual = mode == Mode.AB
-        if dual:
-            _dispatched("dual")
         if spmd and ctx.spmd_kernels and mp and _spmm_ops.shardable(w, mp):
             _dispatched("shard_map")
             out = griffin_matmul(x2, w, block_m=ctx.block_m, dual=dual,
@@ -210,8 +195,7 @@ def griffin_linear(x: jax.Array, w) -> jax.Array:
         return x @ w
     lead = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1])
-    sparse_a = select_mode(ctx.a_sparsity, 0.0,
-                           threshold=ctx.a_threshold) == Mode.A
+    sparse_a = select_mode(ctx.a_sparsity, 0.0) == Mode.A
     if spmd:
         kern_ops = _sparse_a_ops if sparse_a else _dense_ops
         if (ctx.use_kernels and ctx.spmd_kernels and mp

@@ -8,7 +8,7 @@ of it: a frozen dataclass of frozen sections —
 * ``ArenaConfig``  — KV arena: slots, cache_len, paging (page_size/num_pages)
   and KV dtype (``"fp32"`` | ``"int8"``, runtime/paging.py);
 * ``SchedConfig``  — admission policy, fused-chunk ladder, bucketed prefill;
-* ``KernelConfig`` — Pallas kernel dispatch knobs + tuned-plan path;
+* ``KernelConfig`` — Pallas kernel dispatch knobs;
 * ``FaultConfig``  — snapshots, fault-injection spec, straggler eviction;
 * ``RouterConfig`` — multi-replica routing (replicas, queue bound, hedging).
 
@@ -53,7 +53,6 @@ class SchedConfig:
     decode_chunk: int = 8
     measure_every: int = 8
     bucket_prompts: bool = True
-    fused: bool = True
 
 
 @dataclasses.dataclass(frozen=True)
@@ -63,7 +62,6 @@ class KernelConfig:
     spmd_kernels: bool = True
     a_sparsity: Optional[float] = None
     block_m: int = 128
-    plan: Optional[str] = None          # path of a tuned kernel plan (json)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -97,7 +95,6 @@ _LEGACY = {
     "decode_chunk": ("sched", "decode_chunk"),
     "measure_every": ("sched", "measure_every"),
     "bucket_prompts": ("sched", "bucket_prompts"),
-    "fused": ("sched", "fused"),
     "use_kernels": ("kernels", "use_kernels"),
     "interpret": ("kernels", "interpret"),
     "spmd_kernels": ("kernels", "spmd_kernels"),
@@ -199,9 +196,6 @@ class EngineConfig:
         if explicit("spmd_fallback"):
             out = out.replace(kernels=dataclasses.replace(
                 out.kernels, spmd_kernels=not args.spmd_fallback))
-        if explicit("plan"):
-            out = out.replace(kernels=dataclasses.replace(
-                out.kernels, plan=args.plan))
         if explicit("inject_fault"):
             out = out.replace(fault=dataclasses.replace(
                 out.fault, inject=args.inject_fault))

@@ -161,8 +161,7 @@ class Scheduler:
     every step, at most ``max_admissions_per_step`` per tick, so prefill
     work interleaves with decode of the running slots.
     ``policy="static"``: admission only when the pool has fully drained —
-    the classic static-batching baseline whose stragglers idle the pool
-    (benchmarks/bench_serve.py measures the gap).
+    the classic static-batching baseline whose stragglers idle the pool.
 
     Admission is amortized O(1) per request: an arrival-ordered heap feeds
     a ready queue ordered by submission as the clock passes each arrival,
@@ -499,15 +498,13 @@ def _make_paged_insert(axes: Any, spec: PagedSpec,
 def _default_serve_fns(api: ModelApi, cache_len: int, decode_chunk: int = 8):
     """Unsharded single-host jits; the mesh-aware factory is
     ``runtime.serve.jit_serve_fns`` (launch/serve.py passes it in).  The
-    third element is ``chunk_for(n)`` — a memoized fused-chunk jit per scan
-    length on the engine's power-of-two ladder — with the cache/token/
+    second element is ``chunk_for(n)`` — a memoized fused-chunk jit per
+    scan length on the engine's power-of-two ladder — with the cache/token/
     remaining carry donated so the pool arena updates in place."""
     prefill = jax.jit(lambda p, b: api.prefill(p, b, cache_len=cache_len))
-    decode = jax.jit(lambda p, c, t: api.decode_step(p, c, t),
-                     donate_argnums=(1,))
     chunk_for = make_chunk_ladder(
         api, decode_chunk, lambda fn: jax.jit(fn, donate_argnums=(1, 2, 3)))
-    return prefill, decode, chunk_for
+    return prefill, chunk_for
 
 
 def weight_sparsity(params: Any,
@@ -582,7 +579,7 @@ class EngineSnapshot:
 class ServeEngine:
     """Continuous-batching driver over a ``ModelApi``.
 
-    ``fns_factory`` returns (prefill_fn, decode_fn, decode_chunk_fn[, ...])
+    ``fns_factory`` returns (prefill_fn, chunk_for[, ...])
     — pass ``lambda: jit_serve_fns(api, mesh, num_slots, cache_len,
     decode_chunk=...)`` to serve on a mesh (launch/serve.py does); default
     is single-host jits.  The factory is invoked once per selected
@@ -609,13 +606,12 @@ class ServeEngine:
                  fns_factory: Optional[Callable] = None,
                  fault_injector: Optional[FaultInjector] = None,
                  straggler: Optional[StragglerDetector] = None,
-                 plan: Any = None, **legacy: Any):
+                 **legacy: Any):
         # ``config=EngineConfig(...)`` is the construction path (DESIGN.md
         # Section 14); the old flat keywords (num_slots=, cache_len=, ...)
         # still work for one release via the deprecation shim.  Runtime
-        # objects (fns_factory, fault_injector, straggler, the resolved
-        # kernel plan) stay direct arguments — they are not serializable
-        # configuration.
+        # objects (fns_factory, fault_injector, straggler) stay direct
+        # arguments — they are not serializable configuration.
         config = resolve_engine_config(config, legacy, type(self).__name__)
         self.config = config
         self.api = api
@@ -648,25 +644,7 @@ class ServeEngine:
         measure_every = config.sched.measure_every
         decode_chunk = config.sched.decode_chunk
         bucket_prompts = config.sched.bucket_prompts
-        fused = config.sched.fused
         snapshot_dir = config.fault.snapshot_dir
-        # tuned kernel plan (repro.tuning, DESIGN.md Section 12): a
-        # KernelPlan (resolved by this model's family) or a FamilyPlan.
-        # Only the Mode-selection thresholds act here — compaction
-        # granularity was already applied when the caller ran
-        # sparsify_params(plan=...) over these params.  Thresholds change
-        # which kernels trace, never what they compute, so a planned
-        # engine stays token-identical to the default one.
-        fam = plan
-        if plan is not None and hasattr(plan, "families"):
-            fam = plan.family(api.cfg.family)
-        self.plan = fam
-        self._a_threshold = (fam.a_threshold if fam is not None
-                             and fam.a_threshold is not None
-                             else SPARSE_THRESHOLD)
-        self._b_threshold = (fam.b_threshold if fam is not None
-                             and fam.b_threshold is not None
-                             else SPARSE_THRESHOLD)
         self.num_slots = num_slots
         self.cache_len = cache_len
         self.decode_chunk = max(1, decode_chunk)
@@ -677,11 +655,6 @@ class ServeEngine:
         self.chunk_cap: Optional[int] = None
         self.degraded = False
         self.bucket_prompts = bucket_prompts
-        # fused=False keeps the PR 3 per-step hot path (one decode dispatch
-        # + host argmax + sync per token, measurement gathering the full
-        # logits): the benchmark baseline bench_serve.py measures the fused
-        # scan against, and a regression reference for the parity suite
-        self.fused = fused
         self.sched = Scheduler(num_slots, policy, max_admissions_per_step)
         self._fns_factory = fns_factory or (
             lambda: _default_serve_fns(api, cache_len, self.decode_chunk))
@@ -854,13 +827,11 @@ class ServeEngine:
 
     def _select_mode(self) -> Mode:
         return select_mode(self._a_now(), self.b_sparsity,
-                           threshold=self._a_threshold,
-                           b_threshold=(0.0 if self.degraded
-                                        else self._b_threshold))
+                           b_threshold=0.0 if self.degraded else None)
 
     def set_degraded(self, on: bool) -> None:
         """Degradation-ladder level 2 (DESIGN.md Section 13): force the
-        cheaper execution Mode through the PR 8 threshold machinery —
+        cheaper execution Mode through ``select_mode``'s B-side gate —
         ``on`` zeroes the B-side threshold so any pruned weight selects
         the Sparse.B kernels even in the dense-preferred regime (dense
         weights stay dense: 0 > 0 is false either way).  Re-selects
@@ -879,20 +850,19 @@ class ServeEngine:
         if self.mode in (Mode.A, Mode.AB):
             a_scope = (self.a_declared
                        if self.a_declared is not None
-                       and self.a_declared > self._a_threshold
+                       and self.a_declared > SPARSE_THRESHOLD
                        else DEFAULT_DECLARED_A)
         return sparse_execution(use_kernels=self.use_kernels,
                                 interpret=self.interpret,
                                 a_sparsity=a_scope, block_m=self.block_m,
                                 spmd_mesh=self._spmd_mesh,
-                                spmd_kernels=self.spmd_kernels,
-                                a_threshold=self._a_threshold)
+                                spmd_kernels=self.spmd_kernels)
 
-    def _fns(self) -> Tuple[Callable, Callable, Callable]:
+    def _fns(self) -> Tuple[Callable, Callable]:
         fns = self._mode_fns.get(self.mode)
         if fns is None:
             made = self._fns_factory()
-            fns = (made[0], made[1], made[2])
+            fns = (made[0], made[1])
             self._mode_fns[self.mode] = fns
             self.stats["retraces"] += 1
         return fns
@@ -1090,16 +1060,15 @@ class ServeEngine:
         with span("tick", clock=self.clock, mode=self.mode.value):
             if self._recovery_armed():
                 self._snapshot = self._capture()
-            impl = self._step_fused if self.fused else self._step_stepwise
             try:
-                events = impl()
+                events = self._tick()
             except DeviceLoss as loss:
                 self._recover(list(loss.lost), self._snapshot)
-                events = impl()
+                events = self._tick()
             self._observe_hosts(time.perf_counter() - t0)
         return events
 
-    def _step_fused(self) -> List[Tuple[int, int, int]]:
+    def _tick(self) -> List[Tuple[int, int, int]]:
         ev_start = len(self.events)
         pending: List[Tuple[int, int, jax.Array]] = []  # slot, rid, dev tok
         for slot, req in self._admit():
@@ -1125,7 +1094,7 @@ class ServeEngine:
             with span("chunk") as sp:
                 chunk = self._chunk_len(admitted)
                 sp.set_metadata(chunk=chunk, live=len(self.sched.running))
-                chunk_fn = self._fns()[2](chunk)
+                chunk_fn = self._fns()[1](chunk)
                 with self._scope():
                     (self.cache, self._tokens, self._remaining, ring,
                      zf_num, zf_den, kv) = chunk_fn(self.params, self.cache,
@@ -1161,43 +1130,6 @@ class ServeEngine:
                                 kv_blocks_arena=int(kv[1]))
         else:
             self.clock += 1
-        return self.events[ev_start:]
-
-    def _step_stepwise(self) -> List[Tuple[int, int, int]]:
-        """The PR 3 per-step hot path (``fused=False``): one pooled decode
-        dispatch, argmax and ``np.asarray`` sync per token, measurement
-        gathering the live rows of the full (B, vocab) logits.  Kept as the
-        benchmark baseline (bench_serve.py times the fused scan against it)
-        and as a behavioural reference — token output is identical to the
-        fused path by construction."""
-        ev_start = len(self.events)
-        for slot, req in self._admit():
-            cache1, logits = self._prefill(req)
-            self._poll_fault("prefill")
-            tok = self._insert_slot(slot, req, cache1, logits)
-            self.stats["host_syncs"] += 1
-            self._emit(slot, int(tok[0]))
-        active = self.sched.active
-        if active:
-            decode_fn = self._fns()[1]
-            with self._scope():
-                logits, self.cache = decode_fn(self.params, self.cache,
-                                               self._tokens)
-            self._poll_fault("decode")
-            toks = jnp.argmax(logits, -1).astype(jnp.int32)    # (B,)
-            self._tokens = toks[:, None]
-            host = np.asarray(toks)
-            self.stats["host_syncs"] += 1
-            self.stats["decode_steps"] += 1
-            self.stats["live_rows"] += len(active)
-            self._since_measure += 1
-            if self._since_measure >= self.measure_every:
-                self._measure(float(sparsity_of(
-                    logits[jnp.asarray(active)])))
-                self.stats["host_syncs"] += 1
-            for slot in active:
-                self._emit(slot, int(host[slot]))
-        self.clock += 1
         return self.events[ev_start:]
 
     # -- failure handling (DESIGN.md Section 11) ----------------------------

@@ -9,7 +9,7 @@ b_comp sharding its N axis and the kidx/cnt/inv_perm scalar-prefetch
 metadata replicated), and the slot-pool KV arena shards its batch (slot)
 axis over "data" and its head axes over "model"
 (``runtime.sharding.shard_cache(decode=True)``).  Every per-Mode jit set
-(prefill, pooled decode, the fused chunk scan) is traced with explicit
+(prefill and the fused chunk scan) is traced with explicit
 ``in_shardings``/``out_shardings`` plus donation, so the arena updates in
 place *sharded* and only the (chunk, B) token ring, the admissions' first
 tokens, and the live-rows zero-fraction scalars cross back to the host —
@@ -113,7 +113,7 @@ def init_params_sharded(api: ModelApi, mesh: Mesh, key: jax.Array) -> Any:
 def mesh_serve_fns(api: ModelApi, mesh: Mesh, params: Any, num_slots: int,
                    cache_len: int, decode_chunk: int = 8, shardings=None,
                    paged: Optional[PagedSpec] = None):
-    """Returns (prefill_fn, decode_fn, chunk_for, (p_sh, c_sh, rep)) — the
+    """Returns (prefill_fn, chunk_for, (p_sh, c_sh, rep)) — the
     sharded twin of ``runtime.serve.jit_serve_fns``, shaped for
     ``ServeEngine``'s fns factory (one invocation per selected Mode, each
     traced under that Mode's ``sparse_execution`` scope at first call).
@@ -137,13 +137,8 @@ def mesh_serve_fns(api: ModelApi, mesh: Mesh, params: Any, num_slots: int,
     def prefill_fn(params, inp):
         return api.prefill(params, inp, cache_len=cache_len)
 
-    def decode_fn(params, cache, token):
-        return api.decode_step(params, cache, token)
-
     prefill_jit = jax.jit(prefill_fn, in_shardings=(p_sh, rep),
                           out_shardings=(rep, rep))
-    decode_jit = jax.jit(decode_fn, in_shardings=(p_sh, c_sh, rep),
-                         out_shardings=(rep, c_sh), donate_argnums=(1,))
     chunk_for = make_chunk_ladder(
         api, decode_chunk,
         lambda fn: jax.jit(fn,
@@ -151,7 +146,7 @@ def mesh_serve_fns(api: ModelApi, mesh: Mesh, params: Any, num_slots: int,
                            out_shardings=(c_sh, rep, rep, rep, rep, rep,
                                           rep),
                            donate_argnums=(1, 2, 3)))
-    return prefill_jit, decode_jit, chunk_for, (p_sh, c_sh, rep)
+    return prefill_jit, chunk_for, (p_sh, c_sh, rep)
 
 
 class MeshServeEngine(ServeEngine):
@@ -175,16 +170,6 @@ class MeshServeEngine(ServeEngine):
     engine behaves exactly like ``ServeEngine`` with sharding-annotated
     jits.
 
-    Tuned kernel plans (``plan=...``, forwarded to the base engine) need
-    no mesh-specific handling: the family thresholds and per-GEMM
-    ``GriffinWeights.a_thr`` overrides are trace-time constants, so the
-    shard_map'd kernels trace with them exactly like the unsharded ones —
-    the plan tier's mesh cell asserts a plan survives this path
-    (DESIGN.md Section 12).  Plan-steered compaction granularity must
-    still satisfy ``shardable`` (whole N tiles per model shard);
-    ``griffin_linear`` falls back to the decompaction oracle per GEMM
-    otherwise, exactly as for default granularity.
-
     Failure handling (DESIGN.md Section 11): on a detected ``DeviceLoss``
     (or a straggler eviction — hosts are the data-rows of the mesh), the
     inherited recovery rolls back to the tick-start snapshot and this class
@@ -200,7 +185,7 @@ class MeshServeEngine(ServeEngine):
 
     def __init__(self, api: ModelApi, params: Any, *, mesh: Mesh,
                  config=None, fns_factory: Optional[Callable] = None,
-                 fault_injector=None, straggler=None, plan=None, **legacy):
+                 fault_injector=None, straggler=None, **legacy):
         missing = {"data", "model"} - set(mesh.axis_names)
         if missing:
             raise ValueError(f"serving mesh needs axes ('data', 'model'), "
@@ -231,8 +216,7 @@ class MeshServeEngine(ServeEngine):
                 api, self.mesh, self.params, num_slots, cache_len,
                 decode_chunk=self.decode_chunk, shardings=self._shardings)
         super().__init__(api, params, config=config, fns_factory=fns_factory,
-                         fault_injector=fault_injector, straggler=straggler,
-                         plan=plan)
+                         fault_injector=fault_injector, straggler=straggler)
 
     def _init_device_state(self) -> None:
         """Sharded twin of the base allocation: arena placed on the decode

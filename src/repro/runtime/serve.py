@@ -2,8 +2,8 @@
 fused multi-step decode chunk (DESIGN.md Section 9), and prompt-bucket
 padding shared by the engine and its greedy oracle.
 
-``jit_serve_fns`` is the *lockstep* sharded factory (dp logits, pooled
-decode); the mesh-parallel slot-pool engine builds its per-Mode jit sets
+``jit_serve_fns`` is the *lockstep* sharded factory (dp logits); the
+mesh-parallel slot-pool engine builds its per-Mode jit sets
 from ``runtime.mesh_serve.mesh_serve_fns`` instead, which reuses
 ``make_chunk_ladder``/``make_decode_chunk_fn`` below with the serving
 layout's explicit shardings (DESIGN.md Section 10).
@@ -115,7 +115,7 @@ def make_decode_chunk_fn(api: ModelApi, decode_chunk: int) -> Callable:
 def jit_serve_fns(api: ModelApi, mesh: Mesh, batch: int, cache_len: int,
                   fsdp: bool = False, params: Optional[Any] = None,
                   decode_chunk: int = 8):
-    """Returns (prefill_fn, decode_fn, chunk_for, (p_sh, c_sh, logits_sh)).
+    """Returns (prefill_fn, chunk_for, (p_sh, c_sh, logits_sh)).
 
     ``params`` is the tree actually being served — pass it whenever it is
     not shaped like ``api.init``'s output (block-compacted ``GriffinWeights``
@@ -126,12 +126,11 @@ def jit_serve_fns(api: ModelApi, mesh: Mesh, batch: int, cache_len: int,
     These are the fns the serving engine drives (``runtime.engine
     .ServeEngine`` takes ``lambda: jit_serve_fns(...)`` as its fns
     factory): ``prefill_fn`` admits one request (its output cache is
-    slot-inserted into the pool arena), ``decode_fn`` advances the whole
-    pool one step, and ``chunk_for(n)`` returns the fused n-step tick the
-    engine actually serves with (up to ``decode_chunk`` pooled steps per
-    host round-trip; see :func:`make_decode_chunk_fn`) — cache, token and
-    remaining buffers all donated so the arena updates in place.
-    ``logits_sh`` is the dp-sharded logits layout both fns produce — it
+    slot-inserted into the pool arena) and ``chunk_for(n)`` returns the
+    fused n-step tick the engine serves with (up to ``decode_chunk``
+    pooled steps per host round-trip; see :func:`make_decode_chunk_fn`) —
+    cache, token and remaining buffers all donated so the arena updates in
+    place.  ``logits_sh`` is the dp-sharded logits layout of prefill — it
     assumes the pool batch divides the dp axes, so batch-1 admission
     prefills need a 1-dp mesh (multi-host serving buckets prefills on a
     separate dp=1 mesh; see DESIGN.md Section 8).
@@ -151,19 +150,12 @@ def jit_serve_fns(api: ModelApi, mesh: Mesh, batch: int, cache_len: int,
     def prefill_fn(params, inp):
         return api.prefill(params, inp, cache_len=cache_len)
 
-    def decode_fn(params, cache, token):
-        return api.decode_step(params, cache, token)
-
     logits_sh = NamedSharding(mesh, P(*(("pod", "data") if "pod" in
                                         mesh.axis_names else ("data",)),)
                               ) if batch % _dp(mesh) == 0 else rep
     prefill_jit = jax.jit(prefill_fn,
                           in_shardings=(p_sh, None),
                           out_shardings=(c_sh, logits_sh))
-    decode_jit = jax.jit(decode_fn,
-                         in_shardings=(p_sh, c_sh, None),
-                         out_shardings=(logits_sh, c_sh),
-                         donate_argnums=(1,))
     chunk_for = make_chunk_ladder(
         api, decode_chunk,
         lambda fn: jax.jit(fn,
@@ -171,7 +163,7 @@ def jit_serve_fns(api: ModelApi, mesh: Mesh, batch: int, cache_len: int,
                            out_shardings=(c_sh, rep, rep, rep, rep, rep,
                                           rep),
                            donate_argnums=(1, 2, 3)))
-    return prefill_jit, decode_jit, chunk_for, (p_sh, c_sh, logits_sh)
+    return prefill_jit, chunk_for, (p_sh, c_sh, logits_sh)
 
 
 def _dp(mesh: Mesh) -> int:

@@ -30,7 +30,7 @@ Three pieces:
     signal.  Each level is strictly cheaper service, never a fall-over:
     1 shrinks the fused decode chunk (admission latency over batch
     efficiency), 2 forces the cheaper sparse execution Mode through the
-    PR 8 thresholds (``ServeEngine.set_degraded``), 3 sheds the lowest
+    B-side threshold (``ServeEngine.set_degraded``), 3 sheds the lowest
     priority class at admission.  Pressure clearing walks the same
     ladder back up.
 """
@@ -223,7 +223,7 @@ class DegradationLadder:
       0  normal service
       1  halve the fused decode chunk (floor ``min_chunk``) — shorter
          host round-trips, so admissions drain the queue sooner
-      2  force the cheaper execution Mode through the PR 8 thresholds
+      2  force the cheaper execution Mode through the B-side threshold
          (``ServeEngine.set_degraded``: b_threshold -> 0, so pruned
          weights run the Sparse.B kernels even in the dense-preferred
          regime)

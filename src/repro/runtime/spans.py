@@ -9,8 +9,7 @@ microsecond and records nothing.  A span never touches the device: no sync,
 no transfer.  Arguments known only inside the span are attached with
 ``set_metadata`` on the object the ``with`` statement binds.
 
-The fused engine's spans, one tick nested as shown (the stepwise path
-records ``engine.tick`` alone):
+The engine's spans, one tick nested as shown:
 
     engine.tick       ServeEngine.step                 clock, mode
       engine.admit    admission, paged-page reclaim    waiting, free

@@ -116,7 +116,7 @@ def sparsify_params(params: Any, sparsity: float, *, block_k: int = 128,
                     block_n: int = 128, unit: Optional[int] = None,
                     names: Sequence[str] = GEMM_WEIGHTS,
                     min_dim: int = 32, balance: bool = True,
-                    compact: bool = True, plan: Any = None) -> Any:
+                    compact: bool = True) -> Any:
     """Block-prune the weight GEMM leaves of a parameter pytree.
 
     With ``compact=True`` each pruned leaf is replaced by a block-compacted
@@ -130,39 +130,20 @@ def sparsify_params(params: Any, sparsity: float, *, block_k: int = 128,
     (``min_dim`` — tiny projections like mLSTM gate vectors are skipped:
     metadata would outweigh the blocks).  Norm scales, embeddings and
     per-head block-diagonal mats are never touched.
-
-    ``plan`` is a tuned family plan (``repro.tuning.FamilyPlan`` or
-    anything with its ``rule_for(name)`` shape, DESIGN.md Section 12): a
-    matching rule overrides the *compaction* granularity (block sizes /
-    balance unit, clamped to the leaf dims) and stamps the rule's
-    ``a_threshold`` onto the compacted leaf (``GriffinWeights.a_thr``).
-    Pruning deliberately stays at the call's base ``block_k``/``unit``: a
-    plan must never move a zero — compaction at any granularity preserves
-    every surviving value, so planned and default engines stay
-    token-identical (the plan-parity tier asserts this).
     """
     from ..kernels.griffin_spmm.ops import preprocess_weights, stack_weights
 
-    def convert(w: jax.Array, name: str):
+    def convert(w: jax.Array):
         bk = min(block_k, w.shape[-2])
         bn = min(block_n, w.shape[-1])
         un = min(unit or max(8, bn // 4), w.shape[-1])
-        cbk, cbn, cun, thr = bk, bn, un, None
-        rule = plan.rule_for(name) if plan is not None else None
-        if rule is not None:
-            cbk = min(rule.block_k or cbk, w.shape[-2])
-            cbn = min(rule.block_n or cbn, w.shape[-1])
-            cun = min(rule.unit or cun, cbn, w.shape[-1])
-            thr = rule.a_threshold
 
         def one(m):
             return block_prune(m, sparsity, bk, un)
 
         def pre(m):
-            gw = preprocess_weights(np.asarray(m), block_k=cbk, block_n=cbn,
-                                    unit=cun, balance=balance)
-            return (gw if thr is None
-                    else dataclasses.replace(gw, a_thr=thr))
+            return preprocess_weights(np.asarray(m), block_k=bk, block_n=bn,
+                                      unit=un, balance=balance)
 
         if w.ndim == 2:
             wp = one(w)
@@ -195,7 +176,7 @@ def sparsify_params(params: Any, sparsity: float, *, block_k: int = 128,
         if name in names and not blockdiag and hasattr(tree, "ndim") \
                 and tree.ndim >= 2 \
                 and tree.shape[-2] >= min_dim and tree.shape[-1] >= min_dim:
-            return convert(tree, name)
+            return convert(tree)
         return tree
 
     return walk(params)

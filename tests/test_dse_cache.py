@@ -68,10 +68,9 @@ def test_cache_get_put_direct(tmp_path):
 
 
 def test_fingerprint_includes_config_schema_version(monkeypatch):
-    """The candidate-config / kernel-plan schema version (repro.tuning,
-    DESIGN.md Section 12) is part of every sweep fingerprint: a schema
-    bump must cold-start rows cached under the old schema, because the
-    autotuner's scores are only comparable within one schema."""
+    """The candidate-config schema version is part of every sweep
+    fingerprint: a schema bump must cold-start rows cached under the old
+    schema, because scores are only comparable within one schema."""
     import repro.core.dse as dse
 
     base = design_fingerprint(SPARSE_B_STAR, Mode.B, CORE, 1,

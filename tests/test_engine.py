@@ -6,7 +6,7 @@ Three layers (cheap to slow):
     and the fused chunk ladder must run under the same shardings);
   - engine machinery on a trivial fake ``ModelApi`` (slot reuse, event
     attribution, prompt-boundary emission, workload-category re-selection,
-    fused-vs-stepwise equivalence, stale-slot measurement masking);
+    chunk-length token identity, stale-slot measurement masking);
   - decode/prefill parity of registry families against the batch-1
     ``greedy_generate`` oracle under a chunked + bucketed matrix: engine
     tokens == greedy tokens (oracle replaying the same prompt bucket) ==
@@ -24,6 +24,7 @@ import pytest
 from jax.sharding import Mesh
 
 from repro.configs import get_config
+from repro.core.hybrid import SPARSE_THRESHOLD
 from repro.core.spec import Mode
 from repro.models import ModelApi, build_model
 from repro.models.common import sparse_execution
@@ -107,7 +108,7 @@ def test_jit_serve_fns_run_on_one_device_mesh():
     api = build_model(cfg)
     mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("data", "model"))
     B, S, clen = 2, 8, 16
-    prefill_jit, decode_jit, chunk_for, (p_sh, c_sh, logits_sh) = \
+    prefill_jit, chunk_for, (p_sh, c_sh, logits_sh) = \
         jit_serve_fns(api, mesh, B, clen)
     params = api.init(jax.random.PRNGKey(0))
     toks = jnp.ones((B, S), jnp.int32)
@@ -116,20 +117,14 @@ def test_jit_serve_fns_run_on_one_device_mesh():
     # the dp logits sharding is threaded through the jit (it used to be
     # computed and dropped)
     assert logits.sharding.is_equivalent_to(logits_sh, logits.ndim)
-    tok = jnp.argmax(logits, -1).astype(jnp.int32)[:, None]
-    logits2, cache2 = decode_jit(params, cache, tok)
-    assert logits2.shape == (B, cfg.vocab_size)
-    assert logits2.sharding.is_equivalent_to(logits_sh, logits2.ndim)
-    assert int(cache2["pos"]) == S
     # fused chunk under the same shardings: 3 steps advance pos by 3 and
     # fill a (3, B) token ring; dead rows stay out of the measurement
-    cache3, logits3 = prefill_jit(params, {"tokens": toks})
-    tokens = jnp.argmax(logits3, -1).astype(jnp.int32)[:, None]
+    tokens = jnp.argmax(logits, -1).astype(jnp.int32)[:, None]
     remaining = jnp.asarray([3, 0], jnp.int32)
-    cache3, tokens, remaining, ring, zn, zd, kv = chunk_for(3)(
-        params, cache3, tokens, remaining)
+    cache, tokens, remaining, ring, zn, zd, kv = chunk_for(3)(
+        params, cache, tokens, remaining)
     assert ring.shape == (3, B) and ring.dtype == jnp.int32
-    assert int(cache3["pos"]) == S + 2
+    assert int(cache["pos"]) == S + 2
     assert list(np.asarray(remaining)) == [0, 0]
     assert float(zd) == 3.0                     # one live row x three steps
     # no live-KV kernel here: decode_attention reads the whole arena,
@@ -145,8 +140,8 @@ def test_jit_serve_fns_shardings_follow_compacted_params():
     api = build_model(cfg)
     mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("data", "model"))
     params = sparsify_params(api.init(jax.random.PRNGKey(0)), 0.6, **PRUNE)
-    prefill_jit, _, _, (p_sh, _, _) = jit_serve_fns(api, mesh, 2, 16,
-                                                    params=params)
+    prefill_jit, _, (p_sh, _, _) = jit_serve_fns(api, mesh, 2, 16,
+                                                 params=params)
     assert jax.tree.structure(p_sh) == jax.tree.structure(
         jax.tree.map(lambda x: 0, params))
     with sparse_execution(use_kernels=False, interpret=True):
@@ -347,26 +342,28 @@ def test_engine_measurement_ignores_stale_and_unadmitted_slots():
     assert [m for _, m in eng.mode_history] == [Mode.DENSE]
 
 
-def test_engine_fused_and_stepwise_paths_agree():
-    """`fused=False` preserves the PR 3 per-step hot path; both paths must
-    produce identical per-request tokens and attribution counts."""
+def test_engine_chunk_lengths_agree():
+    """One-step chunks and eight-step chunks serve the same trace with
+    identical per-request tokens and token counts: the chunk ladder moves
+    only where the host syncs, never what a request receives."""
     api = fake_api()
     params = api.init(jax.random.PRNGKey(0))
-    rng = np.random.default_rng(5)
-    mk = lambda: [Request(rid=i,
-                          tokens=rng.integers(1, 17, (int(p),), np.int32),
-                          max_new_tokens=int(g), arrival=int(a))
-                  for i, (p, g, a) in enumerate(
-                      zip([3, 7, 2, 5, 4], [6, 1, 9, 3, 5],
-                          [0, 0, 2, 3, 3]))]
-    rng = np.random.default_rng(5)
-    fused = ServeEngine(api, params, num_slots=2, cache_len=32,
-                        decode_chunk=4).run(mk())
-    rng = np.random.default_rng(5)
-    stepwise = ServeEngine(api, params, num_slots=2, cache_len=32,
-                           fused=False).run(mk())
-    assert {r: o.tokens for r, o in fused.items()} == \
-        {r: o.tokens for r, o in stepwise.items()}
+
+    def run(chunk):
+        rng = np.random.default_rng(5)
+        reqs = [Request(rid=i, tokens=rng.integers(1, 17, (p,), np.int32),
+                        max_new_tokens=g, arrival=a)
+                for i, (p, g, a) in enumerate(
+                    zip([3, 7, 2, 5, 4], [6, 1, 9, 3, 5], [0, 0, 2, 3, 3]))]
+        eng = ServeEngine(api, params, num_slots=2, cache_len=32,
+                          decode_chunk=chunk)
+        return eng, {r: o.tokens for r, o in eng.run(reqs).items()}
+
+    one, toks1 = run(1)
+    eight, toks8 = run(8)
+    assert toks1 == toks8
+    assert one.stats["emitted"] == eight.stats["emitted"] == 24
+    assert eight.stats["chunk_calls"] < one.stats["chunk_calls"]
 
 
 def test_chunk_ladder_wastes_no_decode_steps():
@@ -396,9 +393,9 @@ def test_chunk_ladder_is_capped_by_factory():
     params = api.init(jax.random.PRNGKey(0))
     eng = ServeEngine(api, params, num_slots=1, cache_len=16, decode_chunk=4)
     with pytest.raises(ValueError):
-        eng._fns()[2](5)                      # beyond the configured ladder
+        eng._fns()[1](5)                      # beyond the configured ladder
     with pytest.raises(ValueError):
-        eng._fns()[2](0)
+        eng._fns()[1](0)
 
 
 def test_bucket_for_policy():
@@ -502,7 +499,7 @@ def test_engine_parity_dense(family):
 
 @pytest.mark.tier2
 @pytest.mark.parametrize("family", sorted(FAMILY_ARCHS))
-def test_engine_parity_dense_stepwise_chunk1(family):
+def test_engine_parity_dense_chunk1(family):
     _family_parity(FAMILY_ARCHS[family], sparse=False, num_requests=3,
                    decode_chunk=1)
 
@@ -511,6 +508,94 @@ def test_engine_parity_dense_stepwise_chunk1(family):
 @pytest.mark.parametrize("family", SPARSE_FAMILIES)
 def test_engine_parity_sparse(family):
     _family_parity(FAMILY_ARCHS[family], sparse=True, num_requests=3)
+
+
+def _small_trace(cfg):
+    return synthetic_trace(cfg, num_requests=3, seed=3, prompt_lens=(4, 6),
+                           gen_lens=(3, 5), arrival_every=1)
+
+
+def _tokens_of(outs):
+    return {r: list(map(int, o.tokens)) for r, o in outs.items()}
+
+
+def _assert_greedy_under_scope(eng, api, reqs, outs):
+    """Every request's tokens equal the batch-1 greedy oracle's, run on
+    the engine's params under the engine's Mode scope."""
+    for r in reqs:
+        ref = _run_greedy(api, eng.params, r, eng.cache_len,
+                          scope=eng._scope(),
+                          bucket=eng.bucket_for(r.prompt_len))
+        assert outs[r.rid].tokens == list(np.asarray(ref[0])), r.rid
+
+
+@pytest.mark.parametrize("sparse_b,a_declared,mode", [
+    (False, None, Mode.DENSE), (False, 0.9, Mode.A),
+    (True, None, Mode.B), (True, 0.9, Mode.AB)],
+    ids=["dense", "A", "B", "AB"])
+def test_engine_serves_each_mode(sparse_b, a_declared, mode):
+    """The engine's Mode follows the weights and the declared activation
+    sparsity through ``select_mode`` at ``SPARSE_THRESHOLD``, and each
+    Mode's served tokens equal ``greedy_generate`` under that Mode's
+    scope (the Sparse.A, Sparse.B and dual kernels in interpret mode)."""
+    cfg = get_config(FAMILY_ARCHS["transformer"]).reduced()
+    api = build_model(cfg)
+    params = api.init(jax.random.PRNGKey(0))
+    if sparse_b:
+        params = sparsify_params(params, 0.8, **PRUNE)
+    eng = ServeEngine(api, params, num_slots=4, cache_len=16, decode_chunk=3,
+                      use_kernels=True, interpret=True, a_sparsity=a_declared)
+    assert eng.mode == mode
+    reqs = _small_trace(cfg)
+    outs = eng.run(reqs)
+    assert [m for _, m in eng.mode_history] == [mode]
+    _assert_greedy_under_scope(eng, api, reqs, outs)
+
+
+def test_engine_degraded_serves_lightly_pruned_weights_as_sparse_b():
+    """Weights pruned at 0.03 sit under the B threshold, so the engine
+    serves them DENSE; ``set_degraded(True)`` (the SLO ladder's level 2)
+    switches to Sparse.B and back, and the degraded Mode still serves
+    the greedy oracle's tokens."""
+    cfg = get_config(FAMILY_ARCHS["transformer"]).reduced()
+    api = build_model(cfg)
+    params = sparsify_params(api.init(jax.random.PRNGKey(0)), 0.03,
+                             compact=False, **PRUNE)
+    eng = ServeEngine(api, params, num_slots=4, cache_len=16, decode_chunk=3)
+    assert 0.0 < eng.b_sparsity < SPARSE_THRESHOLD
+    assert eng.mode == Mode.DENSE
+    eng.set_degraded(True)
+    assert eng.mode == Mode.B
+    reqs = _small_trace(cfg)
+    outs = eng.run(reqs)
+    _assert_greedy_under_scope(eng, api, reqs, outs)
+    eng.set_degraded(False)
+    assert [m for _, m in eng.mode_history] == [Mode.DENSE, Mode.B,
+                                                Mode.DENSE]
+
+
+@pytest.mark.parametrize("chunk", [1, 3])
+@pytest.mark.parametrize("compacted", [False, True], ids=["dense", "sparseB"])
+@pytest.mark.parametrize("family", ["transformer", "xlstm"])
+def test_compaction_granularity_keeps_tokens(family, compacted, chunk):
+    """Compacting the same pruned weights at 64x64 blocks (balance unit 8)
+    instead of the 16x16 blocks they were pruned at moves no zero: the
+    engine serves exactly the tokens of the pruned-dense twin (``dense``)
+    or of the weights compacted at the pruning blocks (``sparseB``)."""
+    cfg = get_config(FAMILY_ARCHS[family]).reduced()
+    api = build_model(cfg)
+    params = api.init(jax.random.PRNGKey(0))
+    pruned = sparsify_params(params, 0.8, compact=False, **PRUNE)
+    coarse = sparsify_params(pruned, 0.0, block_k=64, block_n=64, unit=8)
+    kern = dict(use_kernels=True, interpret=True)
+    ref_params = sparsify_params(params, 0.8, **PRUNE) if compacted else pruned
+    ref = ServeEngine(api, ref_params, num_slots=4, cache_len=16,
+                      decode_chunk=chunk, **(kern if compacted else {}))
+    got = ServeEngine(api, coarse, num_slots=4, cache_len=16,
+                      decode_chunk=chunk, **kern)
+    ref_toks = _tokens_of(ref.run(_small_trace(cfg)))
+    assert _tokens_of(got.run(_small_trace(cfg))) == ref_toks
+    assert all(len(t) > 0 for t in ref_toks.values())
 
 
 # ---------------------------------------------------------------------------
@@ -533,6 +618,22 @@ def test_engine_config_json_rejects_unknown():
         EngineConfig.from_json('{"nope": {}}')
     with pytest.raises(ValueError):
         EngineConfig.from_json('{"arena": {"slotz": 4}}')
+
+
+@pytest.mark.parametrize("section,field,value", [
+    ("kernels", "plan", "benchmarks/out/kernel_plan.json"),
+    ("sched", "fused", False)], ids=["kernels.plan", "sched.fused"])
+def test_engine_config_json_refuses_retired_fields(section, field, value):
+    """Engine JSON written while the kernel plan and the per-step path
+    existed carries ``kernels.plan`` and ``sched.fused``; neither field
+    exists any more, so such a file is refused by name, not served with
+    the field ignored."""
+    import json
+    from repro.runtime.config import EngineConfig
+    doc = json.loads(EngineConfig().to_json())
+    doc[section][field] = value
+    with pytest.raises(ValueError, match=field):
+        EngineConfig.from_json(json.dumps(doc))
 
 
 def test_engine_config_with_fields_routes_and_rejects():

@@ -52,7 +52,7 @@ from repro.sparsity import sparsify_params
 PRUNE = dict(block_k=16, block_n=16, unit=8)   # reduced dims (d_model 64)
 
 try:
-    from hypothesis import given, settings, strategies as st
+    from hypothesis import example, given, settings, strategies as st
     HAVE_HYPOTHESIS = True
 except ImportError:                                      # pragma: no cover
     HAVE_HYPOTHESIS = False
@@ -352,10 +352,14 @@ if HAVE_HYPOTHESIS:
     @settings(max_examples=6, deadline=None)
     @given(k=st.integers(0, 5),
            phase=st.sampled_from(["admission", "prefill", "decode"]))
+    @example(k=5, phase="prefill")
     def test_recovery_invariant_to_fault_step(k, phase):
         """Property: *when* the fault fires must not change the served
         tokens — every (step, phase) recovery converges to the same trace
-        as the uninterrupted run."""
+        as the uninterrupted run.  A kill is due at the first tick at or
+        after its step that runs its phase; the 5-request trace admits
+        its last request at tick 4, so a prefill kill is drawn no later
+        than the last admitting tick (a later one would never fire)."""
         cfg = get_config("llama3.2-1b").reduced()
         api = build_model(cfg)
         params = api.init(jax.random.PRNGKey(0))
@@ -364,6 +368,10 @@ if HAVE_HYPOTHESIS:
             eng = ServeEngine(api, params, num_slots=3, cache_len=24,
                               decode_chunk=4)
             _PROP_REF[key] = _tokens(eng.run(_trace(cfg, 5)))
+            _PROP_REF["last_admit"] = max(o.admitted
+                                          for o in eng.outputs.values())
+        if phase == "prefill":
+            k = min(k, _PROP_REF["last_admit"])
         inj = FaultInjector(kill_devices=(0,), at_step=k, phase=phase)
         eng = ServeEngine(api, params, num_slots=3, cache_len=24,
                           decode_chunk=4, fault_injector=inj)

@@ -138,6 +138,32 @@ def test_griffin_linear_modes_match_plain_matmul():
                 rtol=1e-4, atol=1e-4)
 
 
+@pytest.mark.parametrize("a_sp,dual", [(SPARSE_THRESHOLD - 1e-3, False),
+                                       (SPARSE_THRESHOLD + 1e-3, True)],
+                         ids=["below", "above"])
+def test_griffin_linear_dual_only_above_threshold(monkeypatch, a_sp, dual):
+    """On compacted weights a scope's declared A sparsity just below
+    ``SPARSE_THRESHOLD`` keeps the Sparse.B kernel and just above it
+    turns on dual predication; the product is the same either way."""
+    import repro.models.common as common
+    seen = []
+    real = common.griffin_matmul
+
+    def spy(*args, **kw):
+        seen.append(kw["dual"])
+        return real(*args, **kw)
+
+    monkeypatch.setattr(common, "griffin_matmul", spy)
+    rng = np.random.RandomState(7)
+    w, gw = _gw(rng, sparsity=0.6)
+    x = jnp.asarray(rng.randn(8, 64), jnp.float32)
+    with sparse_execution(interpret=True, a_sparsity=a_sp):
+        out = griffin_linear(x, gw)
+    assert seen == [dual]
+    np.testing.assert_allclose(np.asarray(out), np.asarray(x @ w),
+                               rtol=1e-4, atol=1e-4)
+
+
 def test_sparsify_params_and_sharding_of_compacted_tree():
     rng = np.random.RandomState(6)
     params = {"layers": {

@@ -10,11 +10,13 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.kernels import (balance_columns, dense_matmul, griffin_matmul,
-                           preprocess_weights, stack_weights)
+from repro.kernels import (GriffinWeights, balance_columns, dense_matmul,
+                           griffin_matmul, preprocess_weights, stack_weights)
 from repro.kernels.dense_gemm.ref import dense_matmul_ref
+from repro.kernels.griffin_spmm.ops import decompact_weights
 from repro.kernels.griffin_spmm.ref import griffin_spmm_ref
-from repro.sparsity import block_prune, magnitude_prune, sparsity_of
+from repro.sparsity import (block_prune, magnitude_prune, sparsify_params,
+                            sparsity_of)
 
 
 def _tol(dtype):
@@ -133,6 +135,63 @@ def test_pruning_hits_target_sparsity():
     assert abs(float(sparsity_of(magnitude_prune(w, 0.8))) - 0.8) < 0.02
     wb = block_prune(w, 0.75, block_k=32, unit=16)
     assert 0.6 < float(sparsity_of(wb)) < 0.9
+
+
+def _gemm_params(seed):
+    rng = np.random.default_rng(seed)
+    return {"layers": [
+        {"wo": rng.standard_normal((64, 64)).astype(np.float32),
+         "w_up": rng.standard_normal((64, 96)).astype(np.float32),
+         "w_down": rng.standard_normal((96, 48)).astype(np.float32)}]}
+
+
+def _griffin_leaves(tree):
+    return [l for l in jax.tree.leaves(
+        tree, is_leaf=lambda x: isinstance(x, GriffinWeights))
+        if isinstance(l, GriffinWeights)]
+
+
+@pytest.mark.parametrize("bk", [16, 32, 64])
+def test_sparsify_params_idempotent(bk):
+    """Two ``sparsify_params`` calls on the same source weights give
+    bit-identical compacted GriffinWeights, and the source is untouched:
+    pruning and compaction hold no rng, cache or mutation."""
+    params = _gemm_params(7)
+    source = jax.tree.map(np.copy, params)
+    kw = dict(block_k=bk, block_n=bk, unit=8)
+    once = _griffin_leaves(sparsify_params(params, 0.7, **kw))
+    twice = _griffin_leaves(sparsify_params(params, 0.7, **kw))
+    assert len(once) == len(twice) == 3
+    for a, b in zip(once, twice):
+        assert (a.k, a.n, a.block_k, a.block_n) == \
+            (b.k, b.n, b.block_k, b.block_n)
+        assert a.block_k == bk
+        for fa, fb in zip((a.b_comp, a.kidx, a.cnt, a.inv_perm),
+                          (b.b_comp, b.kidx, b.cnt, b.inv_perm)):
+            if fa is None or fb is None:
+                assert fa is None and fb is None
+            else:
+                np.testing.assert_array_equal(np.asarray(fa),
+                                              np.asarray(fb))
+    for x, y in zip(jax.tree.leaves(params), jax.tree.leaves(source)):
+        np.testing.assert_array_equal(x, y)
+
+
+def test_sparsify_block_sizes_reach_compacted_leaves():
+    """``block_k`` / ``block_n`` become each compacted leaf's blocks,
+    clamped to the leaf's own dims, and the compacted leaf decompacts to
+    the pruned-dense twin of the same call."""
+    params = _gemm_params(8)
+    kw = dict(block_k=32, block_n=64, unit=8)
+    compact = sparsify_params(params, 0.6, **kw)["layers"][0]
+    dense = sparsify_params(params, 0.6, compact=False, **kw)["layers"][0]
+    for name in ("wo", "w_up", "w_down"):
+        gw = compact[name]
+        k, n = params["layers"][0][name].shape
+        assert (gw.block_k, gw.block_n) == (min(32, k), min(64, n)), name
+        np.testing.assert_array_equal(
+            np.asarray(decompact_weights(gw))[:k, :n],
+            np.asarray(dense[name]))
 
 
 # ---------------------------------------------------------------------------

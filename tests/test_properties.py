@@ -418,72 +418,31 @@ def test_router_hedging_token_exact_property(trace, replicas, hedge_after,
 
 
 # ---------------------------------------------------------------------------
-# autotuner selection + plan application (repro.tuning, DESIGN.md Section 12)
+# sparsify_params: pruning and compaction hold no hidden state
 # ---------------------------------------------------------------------------
-
-@settings(max_examples=40, deadline=None)
-@given(
-    table=st.dictionaries(st.text("abcdxyz_0123456789", min_size=1,
-                                  max_size=10),
-                          st.floats(0.0, 1e4, allow_nan=False,
-                                    allow_infinity=False),
-                          min_size=1, max_size=12),
-    k=st.integers(1, 6), seed=st.integers(0, 999),
-)
-def test_shortlist_and_winner_deterministic_property(table, k, seed):
-    """Shortlist selection and the measured winner are pure functions of
-    a frozen score/measurement table: permuting row order never changes
-    the outcome, ties always break by name."""
-    from repro.tuning.search import select_best, shortlist
-
-    rows = [{"name": n, "score": s} for n, s in table.items()]
-    short = shortlist(rows, k)
-    rng = np.random.default_rng(seed)
-    perm = [rows[i] for i in rng.permutation(len(rows))]
-    assert [r["name"] for r in shortlist(perm, k)] == \
-        [r["name"] for r in short]
-    assert len(short) == min(k, len(rows))
-    scores = [r["score"] for r in short]
-    assert scores == sorted(scores, reverse=True)
-    assert all(r["score"] >= x["score"] for r in short for x in rows
-               if x["name"] not in {s["name"] for s in short})
-
-    shuffled = {n: table[n] for n in rng.permutation(list(table))}
-    winner = select_best(table)
-    assert select_best(shuffled) == winner
-    assert table[winner] == max(table.values())
-    ties = sorted(n for n, v in table.items() if v == table[winner])
-    assert winner == ties[0]                  # deterministic tie-break
-
 
 @settings(max_examples=15, deadline=None)
 @given(
     seed=st.integers(0, 999), sparsity=st.floats(0.2, 0.9),
     bk=st.sampled_from([16, 32, 64]),
-    thr=st.sampled_from([None, 0.05, 0.9]),
 )
-def test_plan_application_idempotent_property(seed, sparsity, bk, thr):
-    """Applying the same kernel plan twice to the same source weights
-    yields bit-identical compacted GriffinWeights — plan application has
-    no hidden state (rng, caches, mutation of the source tree)."""
+def test_sparsify_params_idempotent_property(seed, sparsity, bk):
+    """Compacting the same source weights twice at any block size yields
+    bit-identical GriffinWeights — no hidden state (rng, caches, mutation
+    of the source tree)."""
     from repro.sparsity import sparsify_params
-    from repro.tuning import FamilyPlan, GemmRule
 
     rng = np.random.default_rng(seed)
     params = {"layers": [
         {"wo": rng.standard_normal((64, 64)).astype(np.float32),
          "w_up": rng.standard_normal((64, 96)).astype(np.float32)}]}
-    plan = FamilyPlan(family="x", rules=(
-        GemmRule(match="*", block_k=bk, block_n=bk, unit=8,
-                 a_threshold=thr),))
-    once = sparsify_params(params, sparsity, plan=plan,
-                           block_k=16, block_n=16, unit=8)
-    twice = sparsify_params(params, sparsity, plan=plan,
-                            block_k=16, block_n=16, unit=8)
+    kw = dict(block_k=bk, block_n=bk, unit=8)
+    once = sparsify_params(params, sparsity, **kw)
+    twice = sparsify_params(params, sparsity, **kw)
     for a, b in zip(*(l["layers"][0].values() for l in (once, twice))):
-        assert (a.k, a.n, a.block_k, a.block_n, a.a_thr) == \
-            (b.k, b.n, b.block_k, b.block_n, b.a_thr)
-        assert a.block_k == min(bk, 64) and a.a_thr == thr
+        assert (a.k, a.n, a.block_k, a.block_n) == \
+            (b.k, b.n, b.block_k, b.block_n)
+        assert a.block_k == bk
         for fa, fb in zip((a.b_comp, a.kidx, a.cnt, a.inv_perm),
                           (b.b_comp, b.kidx, b.cnt, b.inv_perm)):
             if fa is None or fb is None:
